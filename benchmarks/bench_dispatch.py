@@ -16,9 +16,10 @@ pre-fused-collectives emulator lowered to one ``BarrierStep`` per sample
 while mesh-bound segments now fuse the whole profile into ONE scan whose
 body runs the shard_map'd collective.  It re-execs python with two forced
 host devices (XLA fixes the device count at first init, so the parent
-process can't build the mesh itself).  Dispatch counts are asserted
-EXACTLY; wall-clock gets a loose regression guard only (shared runners
-swing ~2x run-to-run).
+process can't build the mesh itself), before the parent touches a JAX
+backend, so the child never waits on a device the parent holds.  Dispatch
+counts are asserted EXACTLY; wall-clock gets a loose regression guard only
+(shared runners swing ~2x run-to-run).
 
 Both paths are warmed first (plans built, programs traced) and must report
 bit-identical consumed totals.
@@ -71,10 +72,10 @@ def _collective_child(fast: bool) -> None:
     """Runs inside the forced-2-device subprocess: measure barrier-step
     replay (the old lowering) vs mesh-bound fused segments, assert the
     contracts, print one JSON row on the last stdout line."""
-    import jax
+    from repro.launch.mesh import make_mesh
     n = 256 if fast else 1024
     reps = 5
-    mesh = jax.make_mesh((2,), ("model",))
+    mesh = make_mesh((2,), ("model",))
     em = Emulator(compute_tile=TILE, mem_block=BLOCK, mesh=mesh,
                   plan_cache=PlanCache())
     prof = collective_profile(n)
@@ -137,6 +138,9 @@ def run_collective_scenario(fast: bool) -> dict:
 
 
 def main(fast: bool = False):
+    # the collective child runs first, while this process has not touched
+    # a JAX backend: a parent holding the accelerator would lock it out
+    coll_row = run_collective_scenario(fast)
     n = 256 if fast else 1024
     reps = 5
     em = Emulator(compute_tile=TILE, mem_block=BLOCK,
@@ -167,7 +171,6 @@ def main(fast: bool = False):
         "consumed_hbm_bytes": legacy_rep.consumed.hbm_bytes,
         "consumed_identical": legacy_rep.consumed == fused_rep.consumed,
     }]
-    coll_row = run_collective_scenario(fast)
     rows.append({"scenario": "collective", **coll_row})
     emit("dispatch", rows)
     # Regression guard only: an idle host measures >=3x (the recorded

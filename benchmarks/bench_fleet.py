@@ -108,15 +108,8 @@ def main(fast: bool = False):
     profiles = fleet_profiles(k)
     em = Emulator(plan_cache=PlanCache())
 
-    cfg = FleetConfig.thread(max_workers=WORKERS)
-    em.emulate_many(profiles, config=cfg)                   # warm in-process
-    thread_fleet = None
-    thread_s = float("inf")
-    for _ in range(reps):
-        f = em.emulate_many(profiles, config=cfg)
-        if f.wall_s < thread_s:
-            thread_s, thread_fleet = f.wall_s, f
-
+    # process and agent legs first: their workers need the accelerator,
+    # and this process holds it once it replays anything itself
     bundles = [bundle_profile(em, p) for p in profiles]
     t0 = time.perf_counter()
     fleet = ProcessFleet(WORKERS, WorkerSpec(emulator=em.spec()))
@@ -164,6 +157,15 @@ def main(fast: bool = False):
                 p.wait(timeout=30.0)
             except subprocess.TimeoutExpired:
                 p.kill()
+
+    cfg = FleetConfig.thread(max_workers=WORKERS)
+    em.emulate_many(profiles, config=cfg)                   # warm in-process
+    thread_fleet = None
+    thread_s = float("inf")
+    for _ in range(reps):
+        f = em.emulate_many(profiles, config=cfg)
+        if f.wall_s < thread_s:
+            thread_s, thread_fleet = f.wall_s, f
     em.storage.cleanup()
 
     identical = all(

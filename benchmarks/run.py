@@ -15,6 +15,8 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_atoms, bench_dispatch,
                             bench_emulation_portability,
                             bench_emulation_same_host, bench_fleet,

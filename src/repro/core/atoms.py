@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.calibrate import HostCalibration
+from repro.core.calibrate import HostCalibration, calibrate
 from repro.core.hardware import HardwareSpec
 
 
@@ -173,6 +173,12 @@ class StorageSpec:
 #: collective analogue of ComputeAtom.tile / MemoryAtom.block_bytes — the
 #: schedule compiler quantizes wire bytes into repeats of this block)
 COLL_BLOCK_ELEMS = 1 << 15
+
+#: per-shard float32 elements of one barrier-leg collective launch (16 MiB a
+#: chip).  A larger leg runs as repeated launches of this size plus one
+#: remainder: a profiled prefill's leg can move gigabytes, and its whole
+#: operand would not fit beside the application's weights.
+COLL_CHUNK_ELEMS = 1 << 22
 
 
 def collective_factor(kind: str, n: int) -> float:
@@ -324,8 +330,7 @@ class ComputeAtom(Atom):
                 tile = self.tile
 
                 def burn(x, iters):
-                    del iters  # pallas path: static per-call (rarely used)
-                    return catom_ops.burn(x, iters=1, tile=tile)
+                    return catom_ops.burn(x, iters=iters, tile=tile)
                 self._fn = burn
             else:
                 def burn(x, iters):
@@ -392,11 +397,10 @@ class MemoryAtom(Atom):
         if not self._fns:
             if self.backend == "pallas":
                 from repro.kernels.memory_atom import ops as matom_ops
-                bb = self.block_bytes
 
                 def stream(x, iters):
-                    return matom_ops.stream(x, iters=int(iters),
-                                            block_bytes=bb)
+                    # the buffer streams through VMEM in kernel-sized blocks
+                    return matom_ops.stream(x, iters=iters)
                 self._fns[0] = stream
             else:
                 def stream(x, iters):
@@ -467,7 +471,6 @@ class CollectiveAtom(Atom):
         bounded (psum rescaled by 1/n) because one segment may loop
         thousands of iterations."""
         if self._loop_fn is None:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             mesh, axis, kind = self.mesh, self.axis, self.kind
             n = mesh.shape[axis]
@@ -480,13 +483,14 @@ class CollectiveAtom(Atom):
                     return jax.lax.ppermute(x, axis, perm)
                 return jax.lax.psum(x, axis) * (1.0 / n)
 
-            self._loop_fn = shard_map(local, mesh=mesh, in_specs=P(axis),
-                                      out_specs=P(axis), check_rep=False)
+            self._loop_fn = jax.shard_map(local, mesh=mesh,
+                                          in_specs=P(axis),
+                                          out_specs=P(axis),
+                                          check_vma=False)
         return self._loop_fn
 
     def _coll_fn(self, n_elems: int):
         if n_elems not in self._fns:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             mesh, axis, kind = self.mesh, self.axis, self.kind
 
@@ -499,10 +503,10 @@ class CollectiveAtom(Atom):
                     return jax.lax.ppermute(x, axis, perm)
                 return jax.lax.psum(x, axis)
 
-            fn = shard_map(local, mesh=mesh, in_specs=P(axis),
-                           out_specs=P(axis) if kind not in
-                           ("all-gather",) else P(axis, None),
-                           check_rep=False)
+            fn = jax.shard_map(local, mesh=mesh, in_specs=P(axis),
+                               out_specs=P(axis) if kind not in
+                               ("all-gather",) else P(axis, None),
+                               check_vma=False)
             self._fns[n_elems] = jax.jit(fn)
         return self._fns[n_elems]
 
@@ -539,9 +543,24 @@ class CollectiveAtom(Atom):
         return self._cached(key, lambda: self._build_plan(n_elems))
 
     def _build_plan(self, n_elems: int) -> Plan:
-        fn = self._coll_fn(n_elems)
-        x = jnp.ones((n_elems,), jnp.float32)
-        return Plan(lambda: fn(x), self.quantized_wire_bytes(n_elems))
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        n = self.mesh.shape[self.axis]
+        full, rem = divmod(n_elems, COLL_CHUNK_ELEMS * n)
+        # operands are laid out over the mesh, so each chip holds only its
+        # own shard and no launch reshards from one device
+        legs = [(self._coll_fn(size),
+                 jnp.ones((size,), jnp.float32,
+                          device=NamedSharding(self.mesh, P(self.axis))),
+                 reps)
+                for size, reps in ((COLL_CHUNK_ELEMS * n, full), (rem, 1))
+                if size and reps]
+
+        def launch():
+            for fn, x, reps in legs:
+                for _ in range(reps):
+                    out = fn(x)
+            return out
+        return Plan(launch, self.quantized_wire_bytes(n_elems))
 
     def seconds(self, wire_bytes: float, hw: HardwareSpec) -> float:
         bw = hw.ici_bw * hw.ici_derate
@@ -638,6 +657,6 @@ class StorageAtom(Atom):
         return self.plan_write(nbytes)
 
     def seconds(self, nbytes: float, hw: HardwareSpec) -> float:
-        if self.calib is None:
-            return 0.0
+        if self.calib is None:           # measured on the first storage leg
+            self.calib = calibrate()
         return nbytes / self.calib.storage_write_bps

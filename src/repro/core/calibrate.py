@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import tempfile
 import time
 from dataclasses import asdict, dataclass
@@ -20,7 +21,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-CACHE_PATH = os.path.join(tempfile.gettempdir(), "synapse_host_calib.json")
+
+def cache_path() -> str:
+    """The calibration cache of the default device's kind: a measurement
+    taken on one accelerator (or the CPU) never answers for another."""
+    kind = re.sub(r"[^\w]+", "_", jax.devices()[0].device_kind).strip("_")
+    return os.path.join(tempfile.gettempdir(),
+                        f"synapse_calib_{kind or 'unknown'}.json")
 
 
 @dataclass(frozen=True)
@@ -87,9 +94,10 @@ def measure_storage(nbytes: int = 1 << 24, block: int = 1 << 20):
 
 
 def calibrate(force: bool = False) -> HostCalibration:
-    if not force and os.path.exists(CACHE_PATH):
+    path = cache_path()
+    if not force and os.path.exists(path):
         try:
-            with open(CACHE_PATH) as f:
+            with open(path) as f:
                 return HostCalibration(**json.load(f))
         except Exception:  # noqa: BLE001
             pass
@@ -98,6 +106,6 @@ def calibrate(force: bool = False) -> HostCalibration:
     wr, rd = measure_storage()
     cal = HostCalibration(flops_per_s=flops, stream_bytes_per_s=stream,
                           storage_write_bps=wr, storage_read_bps=rd)
-    with open(CACHE_PATH, "w") as f:
+    with open(path, "w") as f:
         f.write(cal.to_json())
     return cal

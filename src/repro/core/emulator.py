@@ -332,12 +332,12 @@ class EmulatorSpec:
 
     ``build()`` reconstructs an equivalent emulator anywhere — same
     quantization (tile/block sizes), same efficiency/speed knobs, and the
-    *parent's* host calibration, so fleet workers neither re-calibrate nor
-    drift from the emulator that compiled their schedules.  ``mesh`` (a live
-    jax Mesh, built on the destination from its own devices) attaches a
-    CollectiveAtom per the collective spec.
+    *parent's* host calibration when the parent has measured one, so fleet
+    workers neither re-calibrate nor drift from the emulator that compiled
+    their schedules.  ``mesh`` (a live jax Mesh, built on the destination
+    from its own devices) attaches a CollectiveAtom per the collective spec.
     """
-    calib: HostCalibration
+    calib: Optional[HostCalibration] = None
     compute: ComputeSpec = ComputeSpec()
     memory: MemorySpec = MemorySpec()
     storage: StorageSpec = StorageSpec()
@@ -367,13 +367,17 @@ class Emulator:
         the portability benchmark throttles CPU/disk independently via
         ``flops_scale``/``storage_scale`` instead); ``plan_cache``: share
         compiled atom plans across emulators / fleet workers (see
-        ``emulate_many``)."""
-        self.calib = calib or calibrate()
-        self.compute = ComputeAtom(self.calib, tile=compute_tile,
+        ``emulate_many``).
+
+        Construction touches no device: a coordinator that only compiles
+        and ships schedules must leave the accelerator to its workers.
+        The host calibration is measured on first use of ``calib``."""
+        self._calib = calib
+        self.compute = ComputeAtom(calib, tile=compute_tile,
                                    efficiency=efficiency, backend=backend)
-        self.memory = MemoryAtom(self.calib, block_bytes=mem_block,
+        self.memory = MemoryAtom(calib, block_bytes=mem_block,
                                  backend=backend)
-        self.storage = StorageAtom(self.calib, block_bytes=storage_block)
+        self.storage = StorageAtom(calib, block_bytes=storage_block)
         self.collective = CollectiveAtom(mesh) if mesh is not None else None
         self.speed = speed
         self.plan_cache = None
@@ -386,6 +390,14 @@ class Emulator:
                                        collective=self.collective)
         if plan_cache is not None:
             self.set_plan_cache(plan_cache)
+
+    @property
+    def calib(self) -> HostCalibration:
+        if self._calib is None:
+            self._calib = calibrate()
+            for atom in (self.compute, self.memory, self.storage):
+                atom.calib = self._calib
+        return self._calib
 
     def set_plan_cache(self, cache: Optional[PlanCache]) -> None:
         """Route compute/memory/collective plans through a shared cache
@@ -409,7 +421,7 @@ class Emulator:
     def spec(self) -> EmulatorSpec:
         """This emulator's picklable recipe (see ``EmulatorSpec``)."""
         return EmulatorSpec(
-            calib=self.calib, compute=self.compute.spec(),
+            calib=self._calib, compute=self.compute.spec(),
             memory=self.memory.spec(), storage=self.storage.spec(),
             collective=(self.collective.spec()
                         if self.collective is not None else None),

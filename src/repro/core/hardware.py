@@ -9,7 +9,7 @@ HBM, ~50 GB/s/link ICI) and for the local CPU host (calibrated at runtime by
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
-from typing import Dict, Optional, Tuple
+from typing import Dict
 
 
 @dataclass(frozen=True)
@@ -62,6 +62,22 @@ REGISTRY: Dict[str, HardwareSpec] = {
                         HOST_ARCHER_NODE]
 }
 
+#: accelerator peaks keyed by ``jax.Device.device_kind``.  TPU v5e: Google
+#: Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+DEVICE_KINDS: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": TPU_V5E,
+}
+
 
 def get_spec(name: str, chips: int = 1) -> HardwareSpec:
     return REGISTRY[name].with_chips(chips)
+
+
+def spec_for_device_kind(kind: str, chips: int = 1) -> HardwareSpec:
+    """The peak table entry for a device as JAX names it; an unknown kind
+    raises rather than falling back to some other chip's peaks."""
+    try:
+        return DEVICE_KINDS[kind].with_chips(chips)
+    except KeyError:
+        raise KeyError(f"no hardware spec for device kind {kind!r}; known: "
+                       f"{sorted(DEVICE_KINDS)}") from None

@@ -256,19 +256,73 @@ def _dot_flops(instr: Instruction, comp: Computation) -> float:
     return 2.0 * out_numel * k
 
 
+_DIM_LABELS_RE = re.compile(r"dim_labels=([\w]+)_([\w]+)->([\w]+)")
+_WINDOW_RE = re.compile(r"window=\{([^}]*)\}")
+
+
+def _window_attr(window: str, key: str, n: int, default: str) -> List[str]:
+    m = re.search(rf"\b{key}=([\w\-]+)", window)
+    return m.group(1).split("x") if m else [default] * n
+
+
+def _valid_taps(n_in: int, n_out: int, size: int, stride: int, lo: int,
+                lhs_dil: int, rhs_dil: int) -> int:
+    """(output position, window tap) pairs of one spatial dim that land on a
+    real input element rather than on padding or a dilation hole."""
+    span = (n_in - 1) * lhs_dil + 1
+    count = 0
+    for k in range(size):
+        first = lo - k * rhs_dil          # o * stride must reach [first,
+        if lhs_dil == 1:                  # first + span)
+            o_lo = max(0, -(-first // stride))
+            o_hi = min(n_out - 1, (first + span - 1) // stride)
+            count += max(0, o_hi - o_lo + 1)
+            continue
+        for o in range(n_out):
+            pos = o * stride - first
+            if 0 <= pos < span and pos % lhs_dil == 0:
+                count += 1
+    return count
+
+
 def _conv_flops(instr: Instruction, comp: Computation) -> float:
-    # flops = 2 * out_numel * (kernel spatial * in_channels)
-    out_numel = shape_numel(instr.shape)
-    if len(instr.operands) >= 2:
-        rhs = comp.by_name.get(instr.operands[1])
-        if rhs is not None:
-            dims = _shape_dims(rhs.shape)
-            if dims:
-                k = 1
-                for d in dims[:-1]:       # all but output-feature dim (approx)
-                    k *= d
-                return 2.0 * out_numel * k
-    return 2.0 * out_numel
+    """2 x multiply-adds of a convolution, read from its ``dim_labels``.
+
+    The TPU backend emits every matmul as a ``convolution``: feature dims
+    carry the contraction (rhs ``i``), and a batch-like dim may ride as a
+    padded spatial window (``bf0_i0o->b0f``, window 12 padded 11_11), where
+    only the taps that land on real input count.  Layout order varies
+    (``bf_io`` vs ``bf_oi``), so dims are looked up by label, never by
+    position.
+    """
+    m = _DIM_LABELS_RE.search(instr.line)
+    if m is None or len(instr.operands) < 2:
+        return 2.0 * shape_numel(instr.shape)
+    lhs_l, rhs_l, out_l = m.groups()
+    lhs = comp.by_name.get(instr.operands[0])
+    rhs = comp.by_name.get(instr.operands[1])
+    if lhs is None or rhs is None:
+        return 2.0 * shape_numel(instr.shape)
+    lhs_d, rhs_d = _shape_dims(lhs.shape), _shape_dims(rhs.shape)
+    out_d = _shape_dims(instr.shape)
+    macs = float(out_d[out_l.index("b")] * out_d[out_l.index("f")]
+                 * rhs_d[rhs_l.index("i")])
+    n = sum(c.isdigit() for c in out_l)
+    if n:
+        wm = _WINDOW_RE.search(instr.line)
+        window = wm.group(1) if wm else ""
+        size = _window_attr(window, "size", n, "1")
+        stride = _window_attr(window, "stride", n, "1")
+        pad = _window_attr(window, "pad", n, "0_0")
+        lhs_dil = _window_attr(window, "lhs_dilate", n, "1")
+        rhs_dil = _window_attr(window, "rhs_dilate", n, "1")
+        for j in range(n):             # window dim j is spatial label "j"
+            c = str(j)
+            macs *= _valid_taps(
+                lhs_d[lhs_l.index(c)], out_d[out_l.index(c)],
+                int(size[j]), int(stride[j]), int(pad[j].split("_")[0]),
+                int(lhs_dil[j]), int(rhs_dil[j]))
+    return 2.0 * macs
 
 
 _TRIP_RE = re.compile(r'"known_trip_count":\s*\{\s*"n":\s*"(\d+)"')

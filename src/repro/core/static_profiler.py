@@ -66,8 +66,6 @@ def profile_compiled(compiled, *, command: str, tags: Optional[Dict] = None,
             "output_bytes": int(ma.output_size_in_bytes),
         }
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):         # older jaxlib: list per device
-        ca = ca[0] if ca else None
     if ca:
         prof.meta["xla_cost_flops"] = float(ca.get("flops", -1.0))
     return prof

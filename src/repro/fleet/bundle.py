@@ -27,7 +27,7 @@ from repro.fleet.chaos import ChaosPolicy
 @dataclass(frozen=True)
 class MeshSpec:
     """Picklable description of the mesh a worker builds from its own
-    devices (``jax.make_mesh``).  The parent sets
+    devices (``repro.launch.mesh.make_mesh``).  The parent sets
     ``--xla_force_host_platform_device_count=device_count`` in the spawned
     worker's environment so a CPU worker has enough devices to satisfy it.
     """

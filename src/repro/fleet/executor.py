@@ -75,6 +75,7 @@ path, so linear streams replay bit-identically.
 """
 from __future__ import annotations
 
+import glob
 import multiprocessing as mp
 import os
 import statistics
@@ -1076,6 +1077,7 @@ class _PipePeer(Peer):
         super().__init__()
         self.proc = proc
         self.conn = conn
+        self.chip: Optional[int] = None      # TPU chip of a multi-chip host
 
     @property
     def alive(self) -> bool:
@@ -1164,6 +1166,32 @@ class _PipePeer(Peer):
         return f"worker pid {self.proc.pid}"
 
 
+def host_tpu_chips() -> int:
+    """TPU chips this host's processes can open, counted from their device
+    nodes without initializing JAX (a coordinator that did would hold a
+    chip).  Zero where workers run on the CPU."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    n = len(glob.glob("/dev/accel[0-9]*"))
+    try:
+        n += sum(e.isdigit() for e in os.listdir("/dev/vfio"))
+    except OSError:
+        pass
+    return n
+
+
+def tpu_chip_env(chip: int) -> Dict[str, str]:
+    """Environment that confines a process's TPU runtime to one chip of a
+    multi-chip host, so several single-chip processes share the host."""
+    port = 8476 + chip
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
+
+
 class ProcessFleet(FleetBase):
     """A pool of emulator worker processes that replay ``ScheduleBundle``s.
 
@@ -1178,6 +1206,11 @@ class ProcessFleet(FleetBase):
     while queued bundles outnumber free slots, and idle workers are
     retired back to the floor when a stream drains — so a bursty profile
     source pays for exactly the workers its queue depth asked for.
+
+    On a TPU host a chip belongs to one process, so each worker gets a
+    chip of its own and the pool refuses to grow past the host's chips.
+    The coordinator itself must not hold one: it only compiles and ships
+    schedules, which touches no device.
     """
 
     def __init__(self, n_workers: int, spec: WorkerSpec, *,
@@ -1204,6 +1237,12 @@ class ProcessFleet(FleetBase):
         if self._scale_min > n_workers:
             raise ValueError(f"min_workers={min_workers} exceeds "
                              f"n_workers={n_workers}")
+        self._chips = host_tpu_chips()
+        if self._chips and n_workers > self._chips:
+            raise ValueError(
+                f"ProcessFleet asked for {n_workers} workers but this host "
+                f"has {self._chips} TPU chip(s); a chip serves one process, "
+                f"so a TPU host runs at most one worker per chip")
         # -- respawn pacing: exponential backoff + crash-loop breaker -------
         self._backoff_base, self._backoff_cap = respawn_backoff
         self._crash_limit, self._crash_window = crash_loop
@@ -1228,33 +1267,42 @@ class ProcessFleet(FleetBase):
         scope = f"worker:{self._spawned}"
         self._spawned += 1
         parent_conn, child_conn = self._ctx.Pipe()
-        # The mesh's device count must reach the child's XLA before its
-        # backend initializes; setting it in the *parent's* environment
-        # around the spawn is the only ordering that beats every module the
-        # child bootstrap may import.
-        old_flags = os.environ.get("XLA_FLAGS")
+        # The child's device setup must reach its XLA before its backend
+        # initializes; setting it in the *parent's* environment around the
+        # spawn is the only ordering that beats every module the child
+        # bootstrap may import.
+        env: Dict[str, str] = {}
         if self.spec.mesh is not None:
             # append AFTER any inherited flags: XLA takes the last
             # occurrence of a repeated flag, and this repo's own tooling
             # (dryrun, test_distributed) exports its own device-count flag
-            os.environ["XLA_FLAGS"] = (
-                (f"{old_flags} " if old_flags else "")
+            flags = os.environ.get("XLA_FLAGS")
+            env["XLA_FLAGS"] = (
+                (f"{flags} " if flags else "")
                 + f"--xla_force_host_platform_device_count="
                   f"{self.spec.mesh.device_count}")
+        chip = None
+        if self._chips > 1:
+            taken = {p.chip for p in self._peers}
+            chip = min(c for c in range(self._chips) if c not in taken)
+            env.update(tpu_chip_env(chip))
+        saved = {k: os.environ.get(k) for k in env}
+        os.environ.update(env)
         try:
             proc = self._ctx.Process(target=worker_loop,
                                      args=(child_conn, self.spec, scope),
                                      daemon=True)
             proc.start()
         finally:
-            if self.spec.mesh is not None:
-                if old_flags is None:
-                    os.environ.pop("XLA_FLAGS", None)
+            for k, v in saved.items():
+                if v is None:
+                    os.environ.pop(k, None)
                 else:
-                    os.environ["XLA_FLAGS"] = old_flags
+                    os.environ[k] = v
         child_conn.close()
         peer = _PipePeer(proc, parent_conn)
         peer.scope = scope          # flight-recorder track == chaos scope
+        peer.chip = chip
         self._peers.append(peer)
 
     def _refill(self, pending: Deque[int]) -> None:

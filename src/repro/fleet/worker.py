@@ -54,7 +54,9 @@ def _init(spec):
 
     from repro.core.atoms import PlanCache
     from repro.core.schedule import FusedSegment
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     mesh = None
     if spec.mesh is not None:
         if jax.device_count() < spec.mesh.device_count:

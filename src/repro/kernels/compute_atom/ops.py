@@ -7,12 +7,14 @@ import jax.numpy as jnp
 from repro.kernels.compute_atom import kernel
 
 
-@functools.partial(jax.jit, static_argnames=("iters", "tile", "interpret"))
-def _burn(x, *, iters: int, tile: int, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _burn(x, iters, *, interpret):
     return kernel.burn_tile(x, iters=iters, interpret=interpret)
 
 
-def burn(x=None, *, iters: int, tile: int = 256, interpret: bool = True):
+def burn(x=None, *, iters, tile: int = 256, interpret=None):
+    """Burn ``iters`` tile matmuls; ``iters`` is traced, so every count
+    shares one compiled kernel."""
     if x is None:
         x = jnp.eye(tile, dtype=jnp.float32) * 0.5
-    return _burn(x, iters=iters, tile=tile, interpret=interpret)
+    return _burn(x, jnp.asarray(iters, jnp.int32), interpret=interpret)

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -76,7 +78,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
                     block_q: int = 512, block_kv: int = 512,
-                    group: int = 1, interpret: bool = True):
+                    group: int = 1, interpret: Optional[bool] = None):
     """q: [BH, Sq, hd]; k, v: [BKV, Sk, hd] with BH == BKV * group."""
     BH, Sq, hd = q.shape
     BKV, Sk, _ = k.shape
@@ -109,5 +111,5 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -12,7 +12,8 @@ from repro.kernels.flash_attention import kernel
     "causal", "window", "softcap", "block_q", "block_kv", "group",
     "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
-                    block_q=512, block_kv=512, group=1, interpret=True):
+                    block_q=512, block_kv=512, group=1, interpret=None):
+    """Interpret mode follows the platform unless ``interpret`` says."""
     return kernel.flash_attention(
         q, k, v, causal=causal, window=window, softcap=softcap,
         block_q=block_q, block_kv=block_kv, group=group, interpret=interpret)
@@ -20,7 +21,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
 
 def flash_attention_grouped(qg, k, v, *, causal=True, window=None,
                             softcap=None, block_q=512, block_kv=512,
-                            interpret=True):
+                            interpret=None):
     """qg: [B,S,Hk,G,hd]; k/v: [B,S,Hk,hd] -> [B,S,Hk,G,hd]."""
     B, S, Hk, G, hd = qg.shape
     qf = jnp.moveaxis(qg, 1, 3).reshape(B * Hk * G, S, hd)

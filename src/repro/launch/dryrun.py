@@ -122,8 +122,6 @@ def analyze(lowered, compiled, mesh, meta):
             + out["memory"]["temp_bytes"] - out["memory"]["alias_bytes"])
 
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):         # older jaxlib: list per device
-        ca = ca[0] if ca else None
     if ca:
         out["xla_cost"] = {"flops": float(ca.get("flops", -1)),
                            "bytes_accessed": float(ca.get("bytes accessed", -1))}

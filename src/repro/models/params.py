@@ -64,7 +64,8 @@ def _materialize(rng, pd: PDef, path, dtype):
         return (pd.scale * 0.02) * jax.random.normal(key, pd.shape, dt)
     if pd.init == "scaled":  # fan-in scaled (truncated-normal-ish)
         fan_in = pd.shape[0] if len(pd.shape) >= 2 else max(pd.shape[0], 1)
-        std = pd.scale / np.sqrt(fan_in)
+        # a Python float: a NumPy scalar would promote bf16 weights to f32
+        std = float(pd.scale / np.sqrt(fan_in))
         return std * jax.random.normal(key, pd.shape, dt)
     if pd.init == "small":
         return (pd.scale * 1e-3) * jax.random.normal(key, pd.shape, dt)

@@ -30,14 +30,20 @@ class Request:
 
 class Engine:
     def __init__(self, model: Model, params, *, batch_slots: int = 4,
-                 max_len: int = 256, mesh=None):
+                 max_len: int = 256, mesh=None, keep_logits: bool = False):
+        """``keep_logits``: the steps also return their logits, and
+        ``logits`` holds those of the last step served ([B,1,V]), so a
+        caller can check the served numbers against a reference."""
         self.model = model
         self.params = params
         self.B = batch_slots
         self.max_len = max_len
-        self.prefill = jax.jit(make_prefill_step(model, max_len, mesh=mesh))
-        self.decode = jax.jit(make_decode_step(model, mesh=mesh),
-                              donate_argnums=2)
+        self.keep_logits = keep_logits
+        self.logits = None
+        self.prefill = jax.jit(make_prefill_step(
+            model, max_len, mesh=mesh, with_logits=keep_logits))
+        self.decode = jax.jit(make_decode_step(
+            model, mesh=mesh, with_logits=keep_logits), donate_argnums=2)
 
     def serve(self, requests: List[Request]) -> List[Request]:
         """Static batching: pad the wave to batch_slots, prefill, decode to
@@ -53,12 +59,13 @@ class Engine:
         toks = np.zeros((B, plen), np.int32)
         for i, r in enumerate(wave):
             toks[i, plen - len(r.prompt):] = r.prompt   # left-pad
-        tok, cache = self.prefill(self.params, {"tokens": jnp.asarray(toks)})
+        tok, cache, *logits = self.prefill(self.params,
+                                           {"tokens": jnp.asarray(toks)})
         steps = max(r.max_new_tokens for r in wave)
         for i, r in enumerate(wave):
             r.out_tokens.append(int(tok[i, 0]))
         for _ in range(steps - 1):
-            tok, cache = self.decode(self.params, tok, cache)
+            tok, cache, *logits = self.decode(self.params, tok, cache)
             t = np.asarray(tok)
             for i, r in enumerate(wave):
                 if not r.done and len(r.out_tokens) < r.max_new_tokens:
@@ -67,3 +74,5 @@ class Engine:
                     r.done = True
         for r in wave:
             r.done = True
+        if logits:
+            self.logits = logits[0]

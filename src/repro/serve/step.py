@@ -14,13 +14,18 @@ from repro.parallel.sharding import (DECODE_RULES, PREFILL_RULES,
                                      use_sharding)
 
 
-def greedy_token(model: Model, params, hidden_last):
+def _sample(model: Model, params, hidden_last, with_logits: bool):
+    """Greedy token from the last hidden state, plus its logits when the
+    caller checks them against a reference."""
     logits = model.logits(params, hidden_last)       # [B,1,V]
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B,1]
+    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (tok, logits) if with_logits else (tok,)
 
 
 def make_prefill_step(model: Model, max_len: int, src_len: Optional[int] = None,
-                      mesh=None, rules_table=PREFILL_RULES):
+                      mesh=None, rules_table=PREFILL_RULES,
+                      with_logits: bool = False):
+    """``prefill_step(params, batch) -> (tok, cache[, logits])``."""
     def prefill_step(params, batch):
         with use_sharding(mesh, rules_table):
             leaf = batch.get("tokens", batch.get("tgt_tokens",
@@ -30,16 +35,18 @@ def make_prefill_step(model: Model, max_len: int, src_len: Optional[int] = None,
                 if model.cfg.family == "encdec" else \
                 model.init_cache(B, max_len)
             hidden, cache, _ = model.forward(params, batch, cache=cache)
-            tok = greedy_token(model, params, hidden[:, -1:])
-            return tok, cache
+            tok, *logits = _sample(model, params, hidden[:, -1:], with_logits)
+            return (tok, cache, *logits)
     return prefill_step
 
 
-def make_decode_step(model: Model, mesh=None, rules_table=DECODE_RULES):
+def make_decode_step(model: Model, mesh=None, rules_table=DECODE_RULES,
+                     with_logits: bool = False):
+    """``decode_step(params, tokens, cache) -> (tok, cache[, logits])``."""
     def decode_step(params, tokens, cache):
         with use_sharding(mesh, rules_table):
             hidden, cache, _ = model.forward(params, {"tokens": tokens},
                                              cache=cache, decode=True)
-            tok = greedy_token(model, params, hidden)
-            return tok, cache
+            tok, *logits = _sample(model, params, hidden, with_logits)
+            return (tok, cache, *logits)
     return decode_step

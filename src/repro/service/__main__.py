@@ -5,6 +5,7 @@ are documented in :mod:`repro.service.http`.
 """
 import argparse
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.service.http import serve
 
 
@@ -16,6 +17,7 @@ def main(argv=None) -> None:
     ap.add_argument("--port", type=int, default=8787,
                     help="0 picks a free port (printed at startup)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     serve(args.host, args.port)
 
 

@@ -218,7 +218,8 @@ def test_fused_barrier_and_per_sample_replay_are_equivalent():
                               storage_write_bytes=sw,
                               ici_bytes={"all-reduce": ici} if ici else {})
 
-    mesh = jax.make_mesh((2,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("model",))
     em = Emulator(compute_tile=TILE, mem_block=BLOCK, mesh=mesh)
     # alternating wire amounts so _collapse merges nothing, one storage
     # sample so the wire-bearing barrier path is exercised too
@@ -265,7 +266,8 @@ def test_plan_cache_sharers_report_quantized_amount_and_tiny_clamp():
     from repro.core import (Emulator, PlanCache, ResourceVector, Sample,
                             SynapseProfile)
 
-    mesh = jax.make_mesh((2,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("model",))
     em = Emulator(compute_tile=64, mem_block=1 << 18, mesh=mesh,
                   plan_cache=PlanCache())
     atom = em.collective
@@ -335,6 +337,28 @@ def test_plan_cache_sharers_report_quantized_amount_and_tiny_clamp():
         raise SystemExit("expected RuntimeError on quant mismatch")
     except RuntimeError as e:
         assert "quantized for" in str(e)
+    """)
+
+
+@pytest.mark.subproc
+def test_barrier_leg_runs_in_bounded_sharded_chunks():
+    """A barrier leg larger than one chunk per chip runs as repeated
+    chunk launches plus a remainder, on operands laid out over the mesh,
+    and still reports the whole leg's quantized wire bytes."""
+    _run("""
+    import jax
+    from repro.core import atoms
+    from repro.launch.mesh import make_mesh
+
+    atoms.COLL_CHUNK_ELEMS = 8
+    mesh = make_mesh((2,), ("model",))
+    atom = atoms.CollectiveAtom(mesh, axis="model", kind="all-reduce")
+    n_elems = 2 * (3 * 8 + 5)                # 3 full chunks + 5 a chip
+    plan = atom.plan(atom.quantized_wire_bytes(n_elems))
+    assert plan.amount == atom.quantized_wire_bytes(n_elems)
+    assert sorted(atom._fns) == [10, 16]     # chunk and remainder programs
+    out = jax.block_until_ready(plan.launch())
+    assert out.shape == (10,) and len(out.sharding.device_set) == 2
     """)
 
 

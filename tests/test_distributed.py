@@ -59,7 +59,8 @@ def test_sharded_train_step_matches_single_device():
     s0, m0 = step0(state0, batch)
 
     # 2x4 mesh
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = make_rules(mesh, TRAIN_RULES)
     specs = train_state_specs(model, mesh, rules)
     state1 = init_train_state(model, jax.random.key(0))
@@ -104,7 +105,8 @@ def test_sharded_decode_matches_single_device():
         t0, c0 = dec0(params, t0, c0)
         outs0.extend(int(x) for x in np.asarray(t0[:, 0]))
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     pre1 = jax.jit(make_prefill_step(model, max_len=16, mesh=mesh))
     dec1 = jax.jit(make_decode_step(model, mesh=mesh))
     t1, c1 = pre1(params, {"tokens": toks})
@@ -124,7 +126,8 @@ def test_collective_atom_and_walker_agree():
     from repro.core.atoms import CollectiveAtom
     from repro.core.hlo_analysis import analyze_hlo
 
-    mesh = jax.make_mesh((8,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("model",))
     atom = CollectiveAtom(mesh, axis="model", kind="all-reduce")
     wire = 8 * 1024 * 1024.0
     thunk = atom.plan(wire)

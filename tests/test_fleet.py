@@ -145,6 +145,54 @@ def test_mesh_spec_validates_and_counts_devices():
         MeshSpec(shape=(), axes=())
 
 
+class _RecordingContext:
+    """Stands in for the spawn context: records the TPU chip each worker
+    would see at start, and starts nothing."""
+
+    def __init__(self):
+        self.started = []
+        ctx = self
+
+        class Proc:
+            pid = 0
+
+            def __init__(self, **kw):
+                pass
+
+            def start(self):
+                ctx.started.append(os.environ.get("TPU_VISIBLE_CHIPS"))
+
+        self.Process = Proc
+
+    def Pipe(self):
+        return mp.Pipe()
+
+
+def test_process_fleet_gives_each_worker_its_own_tpu_chip(monkeypatch):
+    from repro.fleet import executor
+    ctx = _RecordingContext()
+    monkeypatch.setattr(executor, "host_tpu_chips", lambda: 4)
+    monkeypatch.setattr(executor.mp, "get_context", lambda method: ctx)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    pf = ProcessFleet(3, WorkerSpec(emulator=_em().spec()))
+    assert ctx.started == ["0", "1", "2"]
+    assert [p.chip for p in pf._peers] == [0, 1, 2]
+    assert "TPU_VISIBLE_CHIPS" not in os.environ      # parent env restored
+    pf._peers.pop(1)                                  # chip 1 frees up
+    pf._spawn()
+    assert ctx.started[-1] == "1"
+
+
+def test_process_fleet_refuses_more_workers_than_tpu_chips(monkeypatch):
+    from repro.fleet import executor
+    count_chips = executor.host_tpu_chips
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")      # CPU workers: no limit
+    assert count_chips() == 0
+    monkeypatch.setattr(executor, "host_tpu_chips", lambda: 1)
+    with pytest.raises(ValueError, match="1 TPU chip"):
+        ProcessFleet(2, WorkerSpec(emulator=_em().spec()))
+
+
 def test_process_executor_rejects_per_sample_path():
     em = _em()
     with pytest.raises(ValueError):

@@ -70,7 +70,8 @@ def test_checkpoint_elastic_restore_reshards(tmp_path):
     from jax.sharding import NamedSharding, PartitionSpec as P
     cm = CheckpointManager(str(tmp_path))
     cm.save(1, _state())
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     sh = {"params": {"w": NamedSharding(mesh, P("data")),
                      "b": NamedSharding(mesh, P())},
           "opt": {"mu": {"w": NamedSharding(mesh, P()),
@@ -171,6 +172,35 @@ def test_walker_trip_count_and_collectives_synthetic():
     assert coll["all-reduce"] == pytest.approx(2 * 256 * 3 / 4)
 
 
+# The TPU backend emits matmuls as convolutions; these lines are the shapes
+# and attributes it printed for Qwen2-1.5B's projections and LM head.
+TPU_CONVS = [
+    # x[8,1536] @ w[1536,4096]
+    ("bf16[8,1536]", "bf16[1536,4096]",
+     "bf16[8,4096]{1,0:T(8,128)(2,1)} convolution(%a, %b), "
+     "dim_labels=bf_io->bf", 8 * 4096 * 1536),
+    # LM head against the tied embedding [V, d]: contraction is d, not V
+    ("bf16[8,1536]", "bf16[151936,1536]",
+     "bf16[8,151936]{1,0} convolution(%a, %b), dim_labels=bf_oi->bf",
+     8 * 151936 * 1536),
+    # bsd,dhk->bshk with the head dim riding as a padded window: one real
+    # tap per output position
+    ("bf16[8,1536,1]", "bf16[1536,12,128]",
+     "bf16[8,12,128]{2,0,1} convolution(%a, %b), "
+     "window={size=12 pad=11_11 rhs_reversal=1}, dim_labels=bf0_i0o->b0f",
+     8 * 1536 * 12 * 128),
+]
+
+
+@pytest.mark.parametrize("lhs,rhs,conv,macs", TPU_CONVS,
+                         ids=["bf_io", "bf_oi", "bf0_i0o"])
+def test_walker_counts_tpu_convolutions_by_dim_labels(lhs, rhs, conv, macs):
+    text = (f"HloModule m\n\nENTRY %main (a: {lhs}, b: {rhs}) -> f32[] {{\n"
+            f"  %a = {lhs} parameter(0)\n  %b = {rhs} parameter(1)\n"
+            f"  ROOT %c = {conv}\n}}\n")
+    assert analyze_hlo(text).flops == 2.0 * macs
+
+
 # ---------------------------------------------------------------------------
 # layer plan + cell gating
 # ---------------------------------------------------------------------------
@@ -197,3 +227,66 @@ def test_cell_gating_counts():
             if not ok:
                 assert why
     assert runnable == 32 and skipped == 8
+
+
+# ---------------------------------------------------------------------------
+# device bring-up: peaks by device kind, interpret mode, compile cache
+# ---------------------------------------------------------------------------
+
+def test_hardware_spec_is_looked_up_by_device_kind():
+    from repro.core.hardware import TPU_V5E, spec_for_device_kind
+    assert spec_for_device_kind("TPU v5 lite") == TPU_V5E
+    assert spec_for_device_kind("TPU v5 lite", 4) == TPU_V5E.with_chips(4)
+    with pytest.raises(KeyError, match="no hardware spec"):
+        spec_for_device_kind("TPU v9 imaginary")
+
+
+def test_pallas_interprets_only_on_the_cpu():
+    from repro.kernels import resolve_interpret
+    assert jax.default_backend() == "cpu"
+    assert resolve_interpret() is True
+    assert resolve_interpret(False) is False
+
+
+def _run_python(code: str, **env) -> str:
+    import subprocess
+    import sys
+    full = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    full.update(env)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    full["PYTHONPATH"] = os.path.join(root, "src")
+    out = subprocess.run([sys.executable, "-c", code], env=full, check=True,
+                         capture_output=True, text=True, timeout=120)
+    return out.stdout.strip().splitlines()[-1]
+
+
+def test_emulator_construction_touches_no_device():
+    """A fleet coordinator builds an Emulator before its workers start; it
+    must not hold the accelerator they need."""
+    code = ("from jax._src import xla_bridge\n"
+            "from repro.core import Emulator\n"
+            "em = Emulator()\n"
+            "em.spec()\n"
+            "print(xla_bridge.backends_are_initialized())")
+    assert _run_python(code, JAX_PLATFORMS="cpu") == "False"
+
+
+@pytest.mark.parametrize("env_dir", [False, True], ids=["default", "env"])
+def test_compile_cache_directory(tmp_path, env_dir):
+    """The cache is JAX_COMPILATION_CACHE_DIR when set (JAX reads it, the
+    code sets nothing) and the checkout's fixed directory otherwise; a
+    process held to the CPU keeps none.  No backend is initialized here,
+    so naming a TPU platform only exercises the configuration."""
+    from repro.launch.compile_cache import DEFAULT_DIR
+    code = ("import jax\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "got = enable_compile_cache()\n"
+            "print(got, jax.config.jax_compilation_cache_dir,"
+            " jax.config.jax_persistent_cache_min_compile_time_secs)")
+    env = {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)} if env_dir else {}
+    want = str(tmp_path) if env_dir else DEFAULT_DIR
+    assert _run_python(code, JAX_PLATFORMS="tpu", **env) == \
+        f"{want} {want} 0.0"
+    assert _run_python(code, JAX_PLATFORMS="cpu", **env).startswith("None ")
+    assert os.path.basename(DEFAULT_DIR) == ".jax_cache"

@@ -33,6 +33,23 @@ def test_compute_atom_ops_flops_accounting():
     assert cref.flops(64, 4) == 2 * 64 ** 3 * 4
 
 
+@pytest.mark.parametrize("iters", [3, 7])
+def test_pallas_compute_atom_burns_the_iters_it_reports(iters):
+    """The Pallas-backed atom's plan reports ``iters`` tile matmuls, and
+    its output is the reference burn of exactly that many (each extra
+    iteration moves the result by a quarter of the last step)."""
+    from repro.core.atoms import ComputeAtom, compute_operand
+    atom = ComputeAtom(tile=64, backend="pallas")
+    plan = atom.plan(iters * atom.flops_per_iter())
+    assert plan.amount == iters * atom.flops_per_iter()
+    x = compute_operand(64)
+    got = np.asarray(plan.launch())
+    np.testing.assert_allclose(
+        got, np.asarray(cref.burn_tile(x, iters=iters)), rtol=1e-6)
+    assert not np.allclose(got, np.asarray(cref.burn_tile(x, iters=iters - 1)),
+                           rtol=1e-6)
+
+
 # ---------------------------------------------------------------------------
 # memory atom
 # ---------------------------------------------------------------------------

@@ -307,3 +307,19 @@ def test_banded_attention_matches_full(window, bq, bkv):
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=3e-5, rtol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+def test_serving_init_matches_abstract_dtypes():
+    """Materialized weights take the run's param dtype, as the abstract
+    tree (what the dry-run sizes memory from) says they do."""
+    from repro.configs.run import SERVE_RUN
+    model = build_model(reduced_config(get_config("qwen2-1.5b")), SERVE_RUN)
+    got = jax.eval_shape(model.init, jax.random.key(0))
+    want = model.abstract()
+    assert jax.tree.map(lambda a: a.dtype, got) == \
+        jax.tree.map(lambda a: a.dtype, want)
+    assert {a.dtype for a in jax.tree.leaves(got)} == {jnp.dtype(jnp.bfloat16)}
