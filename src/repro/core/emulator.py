@@ -48,6 +48,7 @@ from repro.core.hardware import HardwareSpec
 from repro.core.metrics import ResourceVector, Sample, SynapseProfile
 from repro.core.schedule import (CompiledSchedule, FusedSegment,
                                  SegmentRunner, compile_schedule)
+from repro.obs.spans import span
 
 #: fleet backends ``emulate_many``/``run_fleet`` accept (see ``repro.fleet``
 #: for the decision matrix)
@@ -74,7 +75,6 @@ class EmulationReport:
     ttc_s: float
     n_samples: int
     consumed: ResourceVector
-    per_sample_s: List[float] = field(default_factory=list)
     planned: Optional[ResourceVector] = None
     mode: str = "per_sample"             # "fused" | "per_sample"
     n_dispatches: int = 0                # device dispatches issued
@@ -109,7 +109,6 @@ class EmulationReport:
         return {"command": self.command, "ttc_s": self.ttc_s,
                 "n_samples": self.n_samples,
                 "consumed": self.consumed.to_dict(),
-                "per_sample_s": list(self.per_sample_s),
                 "planned": (None if self.planned is None
                             else self.planned.to_dict()),
                 "mode": self.mode, "n_dispatches": self.n_dispatches,
@@ -118,10 +117,11 @@ class EmulationReport:
 
     @classmethod
     def from_dict(cls, d: Dict) -> "EmulationReport":
+        """Reads ``to_dict``'s form; keys of older forms that the report
+        no longer has are ignored."""
         return cls(command=d["command"], ttc_s=d["ttc_s"],
                    n_samples=d["n_samples"],
                    consumed=ResourceVector.from_dict(d["consumed"]),
-                   per_sample_s=list(d.get("per_sample_s", ())),
                    planned=(None if d.get("planned") is None
                             else ResourceVector.from_dict(d["planned"])),
                    mode=d.get("mode", "per_sample"),
@@ -442,13 +442,14 @@ class Emulator:
             spec = (self.collective.spec() if self.collective is not None
                     else CollectiveSpec())
             quant = spec.quant_for(mesh_spec)
-        return compile_schedule(_collapse(profile.samples),
-                                compute=self.compute, memory=self.memory,
-                                collective=self.collective,
-                                flops_scale=flops_scale,
-                                mem_scale=mem_scale, speed=self.speed,
-                                keep_collectives=keep_collectives,
-                                collective_quant=quant)
+        with span("synapse.schedule"):
+            return compile_schedule(_collapse(profile.samples),
+                                    compute=self.compute, memory=self.memory,
+                                    collective=self.collective,
+                                    flops_scale=flops_scale,
+                                    mem_scale=mem_scale, speed=self.speed,
+                                    keep_collectives=keep_collectives,
+                                    collective_quant=quant)
 
     def _plan_sample(self, r: ResourceVector, flops_scale=1.0,
                      storage_scale=1.0, mem_scale=1.0):
@@ -479,12 +480,12 @@ class Emulator:
         return thunks, storage_thunks
 
     def _run_per_sample(self, r: ResourceVector, count: int, flops_scale,
-                        storage_scale, mem_scale, consumed, per_sample,
-                        verify: bool):
-        """Replay one collapsed run the per-sample way; returns the updated
-        consumed vector, the number of device dispatches issued, how many
-        of those were executable collectives, and the quantized wire bytes
-        those collectives emulated.
+                        storage_scale, mem_scale):
+        """Replay one collapsed run the per-sample way; returns the
+        consumed vector of each executed sample (``_per_sample_rows``), the
+        number of device dispatches made, how many of those were
+        executable collectives, and the quantized wire bytes those
+        collectives emulated.
 
         Consecutive identical samples with no storage leg execute as a
         single fused consumption (count × amounts): ordering semantics only
@@ -493,43 +494,41 @@ class Emulator:
         launched asynchronously and synced once at the sample barrier;
         storage overlaps on the I/O worker thread.
         """
-        fuse = count > 1 and r.storage_read_bytes == 0 and \
-            r.storage_write_bytes == 0
-        reps = 1 if fuse else count
-        rr = r.scale(count) if fuse else r
-        thunks, storage_thunks = self._plan_sample(
-            rr, flops_scale, storage_scale, mem_scale)
-        dispatches = 0
-        coll_dispatches = 0
-        emulated_ici = 0.0
-        for _ in range(reps):
-            t0 = time.perf_counter()
+        with span("synapse.barrier"):
+            rows = _per_sample_rows(r, count)
+            thunks, storage_thunks = self._plan_sample(
+                rows[0], flops_scale, storage_scale, mem_scale)
+            dispatches = 0
+            coll_dispatches = 0
+            emulated_ici = 0.0
+            # each executed sample is a barrier of its own: one launch, one
+            # sync and one join for each
+            for _ in rows:
+                def io_worker():
+                    for t in storage_thunks:
+                        t()
 
-            def io_worker():
-                for t in storage_thunks:
-                    t()
-
-            th = None
-            if storage_thunks:
-                th = threading.Thread(target=io_worker)
-                th.start()
-            tokens = []
-            for kind, t in thunks:                  # async device dispatch
-                tok = t.launch()
-                if tok is not None:                 # noop plans don't count
-                    tokens.append(tok)
-                    if kind == "ici":
-                        coll_dispatches += 1
-                        emulated_ici += t.amount    # quantized, see atoms
-            dispatches += len(tokens)
-            if tokens:
-                jax.block_until_ready(tokens)       # one sync per sample
-            if th is not None:
-                th.join()
-            per_sample.append(time.perf_counter() - t0)
-            if verify:
-                consumed = consumed.add(rr)
-        return consumed, dispatches, coll_dispatches, emulated_ici
+                th = None
+                if storage_thunks:
+                    th = threading.Thread(target=io_worker)
+                    th.start()
+                tokens = []
+                with span("synapse.barrier.launch"):
+                    for kind, t in thunks:          # async device dispatch
+                        tok = t.launch()
+                        if tok is not None:         # noop plans don't count
+                            tokens.append(tok)
+                            if kind == "ici":
+                                coll_dispatches += 1
+                                emulated_ici += t.amount  # quantized
+                dispatches += len(tokens)
+                if tokens:
+                    with span("synapse.barrier.sync"):
+                        jax.block_until_ready(tokens)  # one sync a sample
+                if th is not None:
+                    with span("synapse.storage"):
+                        th.join()
+        return rows, dispatches, coll_dispatches, emulated_ici
 
     def replay(self, sched: CompiledSchedule, *, command: str = "",
                planned: Optional[ResourceVector] = None,
@@ -567,8 +566,7 @@ class Emulator:
                     f"schedule was quantized for {want} but this "
                     f"emulator's mesh gives {mine}; replaying would emulate "
                     "skewed wire amounts — recompile for this mesh")
-        consumed = ResourceVector()
-        per_sample: List[float] = []
+        executed: List[List[ResourceVector]] = []
         dispatches = 0
         coll_dispatches = 0
         emulated_ici = 0.0
@@ -576,9 +574,7 @@ class Emulator:
         t_start = time.perf_counter()
         for step in sched.steps:
             if isinstance(step, FusedSegment):
-                t0 = time.perf_counter()
                 dispatched = self._segments.run(step)  # ONE dispatch+sync
-                dt = time.perf_counter() - t0
                 dispatches += int(dispatched)
                 if step.mesh_bound:
                     # one executed wire leg per collective-bearing row —
@@ -586,68 +582,60 @@ class Emulator:
                     coll_dispatches += int((step.table[:, 2] > 0).sum())
                     emulated_ici += quant.emulated_bytes(
                         step.collective_iters)
-                # apportion the segment's wall time across its rows so
-                # per_sample_s keeps one entry per executed sample
-                per_sample.extend([dt / step.n_rows] * step.n_rows)
-                if verify:
-                    for rr in step.rows:
-                        consumed = consumed.add(rr)
+                executed.append(step.rows)
             else:
-                consumed, d, c, e = self._run_per_sample(
+                rows, d, c, e = self._run_per_sample(
                     step.resources, step.count, flops_scale,
-                    storage_scale, mem_scale, consumed, per_sample,
-                    verify)
+                    storage_scale, mem_scale)
+                executed.append(rows)
                 dispatches += d
                 coll_dispatches += c
                 emulated_ici += e
-        ttc = time.perf_counter() - t_start
-        return EmulationReport(command=command, ttc_s=ttc,
-                               n_samples=len(per_sample), consumed=consumed,
-                               per_sample_s=per_sample, planned=planned,
-                               mode="fused", n_dispatches=dispatches,
-                               n_collective_dispatches=coll_dispatches,
-                               emulated_ici_bytes=emulated_ici)
+        return _report(executed, verify, t_start, command=command,
+                       planned=planned, mode="fused",
+                       n_dispatches=dispatches,
+                       n_collective_dispatches=coll_dispatches,
+                       emulated_ici_bytes=emulated_ici)
 
     def emulate(self, profile: SynapseProfile, *, flops_scale: float = 1.0,
                 storage_scale: float = 1.0, mem_scale: float = 1.0,
                 verify: bool = True, fused: bool = True) -> EmulationReport:
-        runs = _collapse(profile.samples)
-        use_fused = fused and self._fusable
-        t_start = time.perf_counter()
-        if use_fused:
-            sched = compile_schedule(runs, compute=self.compute,
-                                     memory=self.memory,
-                                     collective=self.collective,
-                                     flops_scale=flops_scale,
-                                     mem_scale=mem_scale, speed=self.speed)
-            rep = self.replay(sched, command=profile.command,
-                              planned=profile.totals,
-                              flops_scale=flops_scale,
-                              storage_scale=storage_scale,
-                              mem_scale=mem_scale, verify=verify)
-            rep.ttc_s = time.perf_counter() - t_start   # include compile
-            return rep
-        consumed = ResourceVector()
-        per_sample: List[float] = []
-        dispatches = 0
-        coll_dispatches = 0
-        emulated_ici = 0.0
-        for r, count in runs:
-            consumed, d, c, e = self._run_per_sample(
-                r, count, flops_scale, storage_scale, mem_scale,
-                consumed, per_sample, verify)
-            dispatches += d
-            coll_dispatches += c
-            emulated_ici += e
-        ttc = time.perf_counter() - t_start
-        return EmulationReport(command=profile.command, ttc_s=ttc,
-                               n_samples=len(per_sample), consumed=consumed,
-                               per_sample_s=per_sample,
-                               planned=profile.totals,
-                               mode="per_sample",
-                               n_dispatches=dispatches,
-                               n_collective_dispatches=coll_dispatches,
-                               emulated_ici_bytes=emulated_ici)
+        with span("synapse.emulate"):
+            with span("synapse.schedule"):
+                runs = _collapse(profile.samples)
+                t_start = time.perf_counter()
+                sched = None
+                if fused and self._fusable:
+                    sched = compile_schedule(runs, compute=self.compute,
+                                             memory=self.memory,
+                                             collective=self.collective,
+                                             flops_scale=flops_scale,
+                                             mem_scale=mem_scale,
+                                             speed=self.speed)
+            if sched is not None:
+                rep = self.replay(sched, command=profile.command,
+                                  planned=profile.totals,
+                                  flops_scale=flops_scale,
+                                  storage_scale=storage_scale,
+                                  mem_scale=mem_scale, verify=verify)
+                rep.ttc_s = time.perf_counter() - t_start  # include compile
+                return rep
+            executed: List[List[ResourceVector]] = []
+            dispatches = 0
+            coll_dispatches = 0
+            emulated_ici = 0.0
+            for r, count in runs:
+                rows, d, c, e = self._run_per_sample(
+                    r, count, flops_scale, storage_scale, mem_scale)
+                executed.append(rows)
+                dispatches += d
+                coll_dispatches += c
+                emulated_ici += e
+            return _report(executed, verify, t_start,
+                           command=profile.command, planned=profile.totals,
+                           mode="per_sample", n_dispatches=dispatches,
+                           n_collective_dispatches=coll_dispatches,
+                           emulated_ici_bytes=emulated_ici)
 
     def emulate_many(self, profiles: Iterable[SynapseProfile], *,
                      flops_scale: float = 1.0, storage_scale: float = 1.0,
@@ -875,6 +863,34 @@ class Emulator:
                            cache_stats=stats, totals=fold.totals,
                            n_samples=n_samples, n_replayed=fold.n_done,
                            recovery=recovery)
+
+
+def _per_sample_rows(r: ResourceVector, count: int) -> List[ResourceVector]:
+    """The consumed vector of each sample a per-sample run executes: a
+    storage-free run of identical samples executes once, count-scaled;
+    any other run once per sample."""
+    if count > 1 and r.storage_read_bytes == 0 and \
+            r.storage_write_bytes == 0:
+        return [r.scale(count)]
+    return [r] * count
+
+
+def _report(executed: List[List[ResourceVector]], verify: bool,
+            t_start: float, **fields) -> EmulationReport:
+    """Account the executed samples' consumption, in execution order (so
+    the totals are bit-identical on every path), and build the report;
+    its ``ttc_s`` runs from ``t_start`` to the end of the accounting."""
+    with span("synapse.account"):
+        consumed = ResourceVector()
+        n_samples = 0
+        for rows in executed:
+            n_samples += len(rows)
+            if verify:
+                for rr in rows:
+                    consumed = consumed.add(rr)
+        return EmulationReport(ttc_s=time.perf_counter() - t_start,
+                               n_samples=n_samples, consumed=consumed,
+                               **fields)
 
 
 def _collapse(samples: List[Sample]):

@@ -52,6 +52,7 @@ from repro.core.atoms import (CollectiveQuant, ComputeAtom, MemoryAtom,
                               compute_burn_body, compute_operand,
                               memory_operand, memory_stream_body)
 from repro.core.metrics import ResourceVector
+from repro.obs.spans import span
 
 
 @dataclass
@@ -374,32 +375,39 @@ class SegmentRunner:
         """Dispatch the whole segment asynchronously; returns the unsynced
         carry (sync with ``jax.block_until_ready``), or ``None`` when every
         row quantized to zero iterations (nothing to dispatch)."""
-        with_c = segment.compute_iters > 0
-        with_m = segment.memory_iters > 0
-        with_coll = segment.collective_iters > 0
-        if not (with_c or with_m or with_coll):
-            return None
-        if with_coll and (self.collective is None
-                          or self.collective.mesh is None):
-            raise RuntimeError(
-                "mesh-bound segment (collective iterations in its table) "
-                "but this runner has no mesh-bound CollectiveAtom; "
-                "recompile the schedule with keep_collectives=True to "
-                "replay wire legs per-sample, or give the emulator a mesh")
-        padded = _next_pow2(segment.n_rows)
-        table = np.zeros((padded, 3), dtype=np.int32)
-        table[:segment.n_rows] = segment.table
-        carry = []
-        if with_c or with_m:       # wire-only segments skip the (big)
-            xc, xm = self._operands()  # compute/memory operands entirely
-            if with_c:
-                carry.append(xc)
-            if with_m:
-                carry.append(xm)
-        if with_coll:
-            carry.append(self._coll_operand())
-        return self._fn(padded, with_c, with_m, with_coll)(tuple(carry),
-                                                           table)
+        with span("synapse.segment.launch"):
+            with_c = segment.compute_iters > 0
+            with_m = segment.memory_iters > 0
+            with_coll = segment.collective_iters > 0
+            if not (with_c or with_m or with_coll):
+                return None
+            if with_coll and (self.collective is None
+                              or self.collective.mesh is None):
+                raise RuntimeError(
+                    "mesh-bound segment (collective iterations in its "
+                    "table) but this runner has no mesh-bound "
+                    "CollectiveAtom; recompile the schedule with "
+                    "keep_collectives=True to replay wire legs per-sample, "
+                    "or give the emulator a mesh")
+            padded = _next_pow2(segment.n_rows)
+            table = np.zeros((padded, 3), dtype=np.int32)
+            table[:segment.n_rows] = segment.table
+            carry = []
+            if with_c or with_m:       # wire-only segments skip the (big)
+                xc, xm = self._operands()  # compute/memory operands
+                if with_c:
+                    carry.append(xc)
+                if with_m:
+                    carry.append(xm)
+            if with_coll:
+                carry.append(self._coll_operand())
+            key = (padded, with_c, with_m, with_coll)
+            fresh = key not in self._fns
+            fn = self._fn(*key)
+            if fresh:               # its first call compiles
+                with span("synapse.segment.compile"):
+                    return fn(tuple(carry), table)
+            return fn(tuple(carry), table)
 
     def run(self, segment: FusedSegment) -> bool:
         """Dispatch and sync: the segment's samples are done on return.
@@ -407,5 +415,6 @@ class SegmentRunner:
         token = self.launch(segment)
         if token is None:
             return False
-        jax.block_until_ready(token)
+        with span("synapse.segment.sync"):
+            jax.block_until_ready(token)
         return True

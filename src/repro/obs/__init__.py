@@ -34,13 +34,20 @@ package gives it one spine:
     scraped at ``repro.service``'s ``/metrics`` endpoint and
     snapshotted into ``FleetReport.obs``.
 
-Nothing here imports jax: events are plain picklable dataclasses and
-the exporters are pure-Python, so the recorder rides inside worker
-processes and over the framed-TCP transport for free.
+``spans``
+    ``span(name)``: the emulator's named phases (``SPANS``) as
+    ``jax.profiler.TraceAnnotation``s, on the device trace's clock, so a
+    profiler trace can split the replay's idle device time by cause.
+
+Nothing here imports jax at import time: events are plain picklable
+dataclasses and the exporters are pure-Python, so the recorder rides
+inside worker processes and over the framed-TCP transport for free;
+``span`` imports ``jax.profiler`` on its first call.
 """
 from repro.obs.clock import ClockSync, anchor, now, wall
 from repro.obs.metrics import MetricsRegistry, parse_promtext
 from repro.obs.recorder import Event, FlightRecorder, ObsFrame
+from repro.obs.spans import SPANS, span
 from repro.obs.trace import (slo_windows_ms, to_chrome_trace,
                              validate_trace, write_trace)
 
@@ -49,4 +56,5 @@ __all__ = [
     "Event", "FlightRecorder", "ObsFrame",
     "slo_windows_ms", "to_chrome_trace", "validate_trace", "write_trace",
     "MetricsRegistry", "parse_promtext",
+    "SPANS", "span",
 ]

@@ -71,7 +71,6 @@ def test_fused_matches_legacy_storage_free():
     assert fused.consumed == legacy.consumed
     assert fused.consumed == prof.totals
     assert fused.n_samples == legacy.n_samples == 32
-    assert len(fused.per_sample_s) == len(legacy.per_sample_s)
     # O(1) dispatches fused vs O(M x atoms) per-sample
     assert fused.n_dispatches == 1
     assert legacy.n_dispatches == 32 * 2
