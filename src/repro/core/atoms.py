@@ -7,8 +7,10 @@ Paper §IV-B, adapted per DESIGN.md §2:
                      (emulate an app running below peak).  Backends: jnp
                      (XLA loop) or the Pallas kernel in
                      ``repro.kernels.compute_atom`` (TPU target).
-  * MemoryAtom     — streams a target byte count through the memory system
-                     (Pallas: HBM→VMEM block copies; jnp: scaled copy loop).
+  * MemoryAtom     — streams a target byte count through HBM (Pallas:
+                     HBM→VMEM block copies; jnp: a ring of blocks larger than
+                     the chip's on-chip memory, one block read, scaled and
+                     written back in place per iteration).
   * CollectiveAtom — moves an exact wire-byte count over a mesh axis with
                      psum/all_gather/ppermute under shard_map (the paper's
                      "planned" network atom, first-class here).
@@ -80,7 +82,9 @@ class PlanCache:
     concurrent fleet workers building *different* plans trace concurrently,
     while a second worker asking for a key mid-build waits for the first
     builder instead of constructing a duplicate.  The returned plans are
-    safe to execute concurrently (jitted callables with read-only operands).
+    safe to execute concurrently: jitted callables with read-only operands,
+    except the memory atom's ring, which its plans take in turn under the
+    atom's lock.
     """
 
     def __init__(self):
@@ -284,15 +288,47 @@ def compute_operand(tile: int):
     return jnp.eye(tile, dtype=jnp.float32) * 0.5
 
 
-def memory_stream_body(_, c):
-    """One memory-atom iteration: a full read+write pass over the block."""
-    return c * 1.0000001
+def ring_windows(block_bytes: int, platform: str) -> int:
+    """How many ``block_bytes`` windows the memory leg's ring holds on
+    ``platform`` (a ``jax.default_backend()`` name).  On a TPU the ring is
+    twice the on-chip memory (VMEM: 128 MiB on v5e), so XLA cannot keep
+    the working set there and every pass's window streams through HBM.
+    Elsewhere it is 4 MiB: small blocks still make a ring that wraps
+    round, and the default 16 MiB block is a ring of one."""
+    want = 2 * (128 << 20) if platform == "tpu" else 4 << 20
+    return max(1, -(-want // block_bytes))
+
+
+def memory_stream_body(_, state):
+    """One memory-atom iteration over the ring state ``(ring, window)``
+    that ``memory_operand`` makes: read window ``window`` of the ring,
+    scale it and write it back in place (one read and one write pass of
+    the block), then move to the next window."""
+    ring, window = state
+    x = jax.lax.dynamic_index_in_dim(ring, window, keepdims=False)
+    ring = jax.lax.dynamic_update_index_in_dim(ring, x * 1.0000001, window, 0)
+    return ring, (window + 1) % ring.shape[0]
 
 
 def memory_operand(block_bytes: int):
-    """The stream loop's carry (one block); shared with the schedule
-    compiler for the same reason as ``compute_operand``."""
-    return jnp.ones((block_bytes // 4,), jnp.float32)
+    """The stream loop's carry: a ring of ``ring_windows`` blocks on the
+    default device, and the window the next iteration works on.  Shared
+    with the schedule compiler for the same reason as ``compute_operand``.
+
+    A block is a ``(rows, 128)`` window on the ring's leading axis, so its
+    offset is aligned to the TPU's tiles whatever the window: the slice,
+    scale and update then fuse into one in-place pass over HBM.  (At a
+    flat offset XLA cannot prove aligned, the update is a separate op
+    that took four times as long as the read on a v5e.)  Programs that
+    carry the ring donate it, and their caller keeps the returned ring for
+    the next launch: a ring that is not donated is copied at every
+    launch."""
+    if block_bytes % 512:
+        raise ValueError(f"memory block of {block_bytes} bytes is not a "
+                         "whole number of 128-lane float32 rows")
+    windows = ring_windows(block_bytes, jax.default_backend())
+    return (jnp.ones((windows, block_bytes // 512, 128), jnp.float32),
+            jnp.int32(0))
 
 
 # ---------------------------------------------------------------------------
@@ -384,29 +420,29 @@ class MemoryAtom(Atom):
         self.calib = calib
         self.block_bytes = block_bytes
         self.backend = backend
-        self._fns: Dict[int, Callable] = {}
-        self._fn_lock = threading.Lock()
+        self._fn: Optional[Callable] = None
+        self._lock = threading.Lock()
+        self._ring = None            # (ring, window), made on first launch
 
     def _stream_fn(self):
         # guarded like ComputeAtom._loop_fn: concurrent distinct-key plan
         # builds must share one jitted program
-        with self._fn_lock:
-            return self._stream_fn_locked()
+        with self._lock:
+            if self._fn is None:
+                if self.backend == "pallas":
+                    from repro.kernels.memory_atom import ops as matom_ops
 
-    def _stream_fn_locked(self):
-        if not self._fns:
-            if self.backend == "pallas":
-                from repro.kernels.memory_atom import ops as matom_ops
-
-                def stream(x, iters):
-                    # the buffer streams through VMEM in kernel-sized blocks
-                    return matom_ops.stream(x, iters=iters)
-                self._fns[0] = stream
-            else:
-                def stream(x, iters):
-                    return jax.lax.fori_loop(0, iters, memory_stream_body, x)
-                self._fns[0] = jax.jit(stream)
-        return self._fns[0]
+                    def stream(x, iters):
+                        # the block streams through VMEM in kernel blocks
+                        return matom_ops.stream(x, iters=iters)
+                    self._fn = stream
+                else:
+                    def stream(ring, window, iters):
+                        return jax.lax.fori_loop(0, iters,
+                                                 memory_stream_body,
+                                                 (ring, window))
+                    self._fn = jax.jit(stream, donate_argnums=0)
+            return self._fn
 
     def spec(self) -> MemorySpec:
         return MemorySpec(block_bytes=self.block_bytes, backend=self.backend)
@@ -428,8 +464,22 @@ class MemoryAtom(Atom):
 
     def _build_plan(self, iters: int) -> Plan:
         fn = self._stream_fn()
-        x = memory_operand(self.block_bytes)
-        return Plan(lambda: fn(x, iters), iters * self.bytes_per_iter())
+        amount = iters * self.bytes_per_iter()
+        if self.backend == "pallas":
+            x = jnp.ones((self.block_bytes // 4,), jnp.float32)
+            return Plan(lambda: fn(x, iters), amount)
+
+        def launch():
+            # the ring is donated: one launch at a time takes it, and the
+            # next launch gets the ring this one returns.  The window index
+            # (not donated) is the token to sync on: the next launch may
+            # delete the ring before this one's caller syncs
+            with self._lock:
+                if self._ring is None:
+                    self._ring = memory_operand(self.block_bytes)
+                self._ring = fn(*self._ring, iters)
+                return self._ring[1]
+        return Plan(launch, amount)
 
     def seconds(self, nbytes: float, hw: HardwareSpec) -> float:
         bw = hw.hbm_bw * hw.hbm_derate

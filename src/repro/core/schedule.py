@@ -13,8 +13,8 @@ programs instead:
     atoms quantize (``ComputeAtom.iters_for`` / ``MemoryAtom.iters_for`` /
     ``CollectiveQuant.iters_for``, applied to the count-scaled run
     amounts).  A segment executes as ONE jitted ``lax.scan`` over its
-    table — the scan carries the compute tile, the memory block, and (for
-    **mesh-bound** segments, i.e. those with wire-byte rows) a fixed
+    table — the scan carries the compute tile, the memory leg's ring, and
+    (for **mesh-bound** segments, i.e. those with wire-byte rows) a fixed
     shard_map-collective block through every row in order, so the
     cross-sample ordering contract holds *inside* the program and an
     M-sample profile costs O(storage-segment boundaries) dispatches
@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.atoms import (CollectiveQuant, ComputeAtom, MemoryAtom,
                               compute_burn_body, compute_operand,
@@ -286,13 +287,17 @@ class SegmentRunner:
     One program per (padded length, needs-compute, needs-memory,
     needs-collective); safe to share across fleet worker threads: the
     program dict and operand init are guarded, jitted callables are
-    thread-safe, and operands are read-only.
+    thread-safe, and operands are read-only but for the ring below.
 
     ``collective`` (a mesh-bound ``CollectiveAtom``) supplies the
     shard_map'd per-iteration wire step and its fixed-block operand;
     without one, launching a mesh-bound segment raises — a meshless
     replayer must recompile with ``keep_collectives=True`` instead of
     silently dropping wire work.
+
+    The memory leg's ring (``memory_operand``) is donated to each program
+    that carries it, and the ring a launch returns is the next launch's
+    operand: launches take it in turn under the runner's lock.
     """
 
     def __init__(self, tile: int = 256, block_bytes: int = 1 << 24,
@@ -303,39 +308,26 @@ class SegmentRunner:
         self._fns: Dict[tuple, object] = {}
         self._lock = threading.Lock()
         self._xc = None
-        self._xm = None
+        self._ring = None
         self._xcoll = None
-
-    def _operands(self):
-        if self._xm is None:
-            with self._lock:
-                if self._xm is None:
-                    # atom-shared constructors: a fused iteration must cost
-                    # exactly what an atom iteration costs.  _xm is the
-                    # publish flag — it is assigned last, so a racing reader
-                    # never sees one operand without the other.
-                    self._xc = compute_operand(self.tile)
-                    self._xm = memory_operand(self.block_bytes)
-        return self._xc, self._xm
 
     def set_collective(self, atom) -> None:
         """Swap the collective atom, dropping every mesh-bound program and
         the collective operand — they close over the OLD atom's shard_map
-        mesh, and the program key carries no mesh identity."""
+        mesh, and the program key carries no mesh identity — and the ring,
+        which a mesh-bound program leaves laid out over the old mesh."""
         with self._lock:
             self.collective = atom
             self._xcoll = None
+            self._ring = None
             self._fns = {k: v for k, v in self._fns.items() if not k[3]}
-
-    def _coll_operand(self):
-        if self._xcoll is None:
-            with self._lock:
-                if self._xcoll is None:
-                    self._xcoll = self.collective.loop_operand()
-        return self._xcoll
 
     def _fn(self, padded_len: int, with_c: bool, with_m: bool,
             with_coll: bool):
+        """The segment program ``fn(ring, carry, table) -> (ring, carry)``:
+        ``carry`` holds, in order, the compute tile, the ring's window
+        index and the collective block of the legs it runs, ``ring`` the
+        memory leg's ring (donated), or ``()`` without a memory leg."""
         key = (padded_len, with_c, with_m, with_coll)
         fn = self._fns.get(key)
         if fn is None:
@@ -350,6 +342,7 @@ class SegmentRunner:
                         blocks.append(lambda v, row: jax.lax.fori_loop(
                             0, row[0], compute_burn_body, v))
                     if with_m:
+                        m = len(blocks)
                         blocks.append(lambda v, row: jax.lax.fori_loop(
                             0, row[1], memory_stream_body, v))
                     if with_coll:
@@ -357,13 +350,20 @@ class SegmentRunner:
                         blocks.append(lambda v, row: jax.lax.fori_loop(
                             0, row[2], lambda _, x: coll_step(x), v))
 
-                    def segment(carry, table):
+                    def segment(ring, carry, table):
+                        state = list(carry)
+                        if with_m:
+                            state[m] = (ring, state[m])
+
                         def body(c, row):
                             return tuple(b(v, row) for b, v
                                          in zip(blocks, c)), jnp.int32(0)
-                        out, _ = jax.lax.scan(body, carry, table)
-                        return out
-                    fn = jax.jit(segment)
+                        out, _ = jax.lax.scan(body, tuple(state), table)
+                        out = list(out)
+                        if with_m:
+                            ring, out[m] = out[m]
+                        return ring, tuple(out)
+                    fn = jax.jit(segment, donate_argnums=0)
                     self._fns[key] = fn
         return fn
 
@@ -392,22 +392,40 @@ class SegmentRunner:
             padded = _next_pow2(segment.n_rows)
             table = np.zeros((padded, 3), dtype=np.int32)
             table[:segment.n_rows] = segment.table
-            carry = []
-            if with_c or with_m:       # wire-only segments skip the (big)
-                xc, xm = self._operands()  # compute/memory operands
-                if with_c:
-                    carry.append(xc)
-                if with_m:
-                    carry.append(xm)
-            if with_coll:
-                carry.append(self._coll_operand())
             key = (padded, with_c, with_m, with_coll)
             fresh = key not in self._fns
             fn = self._fn(*key)
-            if fresh:               # its first call compiles
-                with span("synapse.segment.compile"):
-                    return fn(tuple(carry), table)
-            return fn(tuple(carry), table)
+            with self._lock:
+                # atom-shared constructors: a fused iteration must cost
+                # exactly what an atom iteration costs
+                if with_c and self._xc is None:
+                    self._xc = compute_operand(self.tile)
+                if with_m and self._ring is None:
+                    self._ring = memory_operand(self.block_bytes)
+                    mesh = getattr(self.collective, "mesh", None)
+                    if mesh is not None:
+                        # where a mesh-bound program leaves it, so every
+                        # program of this runner runs on the same devices
+                        self._ring = jax.device_put(
+                            self._ring, NamedSharding(mesh, P()))
+                if with_coll and self._xcoll is None:
+                    self._xcoll = self.collective.loop_operand()
+                carry, ring = [], ()
+                if with_c:
+                    carry.append(self._xc)
+                if with_m:
+                    ring = self._ring[0]
+                    carry.append(self._ring[1])
+                if with_coll:
+                    carry.append(self._xcoll)
+                if fresh:               # its first call compiles
+                    with span("synapse.segment.compile"):
+                        ring, out = fn(ring, tuple(carry), table)
+                else:
+                    ring, out = fn(ring, tuple(carry), table)
+                if with_m:             # the window index after the compute
+                    self._ring = (ring, out[int(with_c)])
+            return out
 
     def run(self, segment: FusedSegment) -> bool:
         """Dispatch and sync: the segment's samples are done on return.

@@ -11,7 +11,9 @@ Pins the ISSUE 2 contracts:
     O(M x atoms) per-sample;
   * PlanCache builds different keys concurrently (per-key build locks)
     with exact stats; StorageAtom pre-creates the read scratch file at
-    plan time; emulate_many caps its pool at len(profiles).
+    plan time; emulate_many caps its pool at len(profiles);
+  * the memory leg walks a ring of blocks, one block a pass, on both
+    paths, and runs exactly the passes the schedule's table counts.
 """
 import os
 import threading
@@ -23,6 +25,7 @@ import pytest
 from repro.core import (BarrierStep, Emulator, FusedSegment, Plan, PlanCache,
                         ResourceVector, Sample, StorageAtom, SynapseProfile,
                         compile_schedule)
+from repro.core.atoms import ring_windows
 from repro.core.emulator import _collapse
 
 # Small tile/block keep device work tiny while staying above the atoms'
@@ -275,3 +278,96 @@ def test_emulate_many_caps_workers():
     fleet = em.emulate_many(profs, max_workers=8)
     assert fleet.max_workers == 2             # capped at len(profiles)
     assert fleet.n_profiles == 2
+
+
+# ---------------------------------------------------------------------------
+# the memory leg's ring
+# ---------------------------------------------------------------------------
+
+#: windows in the CPU backend's ring at BLOCK: more than one, so a pass
+#: count above it wraps round
+R = ring_windows(BLOCK, "cpu")
+
+
+def _passes(state):
+    """Passes each window of a ring state has had.  A pass scales its
+    window by 1 + 2**-23 (1.0000001 in float32), and c passes from 1.0
+    give exactly 1 + c * 2**-23 while c < 2**22."""
+    ring, window = (np.asarray(a) for a in state)
+    w = ring.reshape(R, -1)
+    assert (w == w[:, :1]).all()              # a pass moves a whole window
+    return np.rint((w[:, 0].astype(np.float64) - 1) * 2 ** 23).astype(int), \
+        int(window)
+
+
+def _rotated(n):
+    """Passes per window, and the next window, after n passes from 0."""
+    return np.bincount(np.arange(n) % R, minlength=R), n % R
+
+
+def _ring_profile():
+    # runs of more than R passes, distinct so none collapse
+    return _profile([_rv(flops=FPI, hbm=(R + 3) * BPI),
+                     _rv(hbm=(2 * R + 1) * BPI),
+                     _rv(flops=2 * FPI, hbm=5 * BPI)])
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_memory_leg_rotates_through_every_window(fused):
+    """Past R passes the ring wraps round: each window gets the passes
+    that fall on it, on the fused and the per-sample path alike, and the
+    next replay carries on from the window the last one stopped at."""
+    assert R > 1
+    em = _em()
+    prof = _ring_profile()
+    n = em.compile(prof).describe()["memory_iters"]
+    assert n > 2 * R
+    em.emulate(prof, fused=fused)
+    em.emulate(prof, fused=fused)
+    state = em._segments._ring if fused else em.memory._ring
+    got, window = _passes(state)
+    want, want_window = _rotated(2 * n)
+    np.testing.assert_array_equal(got, want)
+    assert window == want_window
+
+
+def test_ring_replays_consume_bit_identical_totals():
+    """Over more than R passes a row, fused and per-sample replays report
+    bit-identical consumed totals, and each ran exactly the passes its
+    schedule counts."""
+    fused_em, legacy_em = _em(), _em()
+    prof = _ring_profile()
+    fused = fused_em.emulate(prof, fused=True)
+    legacy = legacy_em.emulate(prof, fused=False)
+    assert fused.consumed == legacy.consumed == prof.totals
+    n = fused_em.compile(prof).describe()["memory_iters"]
+    assert _passes(fused_em._segments._ring)[0].sum() == n
+    assert _passes(legacy_em.memory._ring)[0].sum() == n
+
+
+def test_ring_keeps_the_memory_quantization():
+    """The ring changes where a pass reads and writes, not what it is
+    charged: two block passes an iteration, rounded per row, so the bench's
+    burned-bytes check (within half an iteration a row) holds as before."""
+    em = _em()
+    assert em.memory.bytes_per_iter() == 2 * BLOCK
+    prof = _ring_profile()
+    desc = em.compile(prof).describe()
+    assert desc["memory_iters"] == sum(
+        em.memory.iters_for(s.resources.hbm_bytes) for s in prof.samples)
+    burned = abs(desc["memory_iters"] * em.memory.bytes_per_iter()
+                 - prof.totals.hbm_bytes) / em.memory.bytes_per_iter()
+    assert burned <= 0.5 * desc["n_rows"]
+    # amounts off the block grid round to the nearest pass, as before
+    assert em.memory.iters_for((R + 0.4) * BPI) == R
+    assert em.memory.iters_for((R + 0.6) * BPI) == R + 1
+
+
+def test_memory_block_must_be_whole_lane_rows():
+    """A block is a (rows, 128) window of float32: a block size off that
+    grid would charge bytes its passes never move."""
+    from repro.core.atoms import memory_operand
+    ring, window = memory_operand(BLOCK)
+    assert ring.shape == (R, BLOCK // 512, 128) and int(window) == 0
+    with pytest.raises(ValueError, match="128-lane"):
+        memory_operand(BLOCK + 4)
