@@ -21,10 +21,14 @@ while bodies by their parsed trip counts, and accounts:
         reduce-scatter   size_out·(n-1)          (input = out·n)
         all-to-all       size·(n-1)/n
         collective-permute  size
-    attributed to a mesh axis by replica-group stride.
+    attributed to a mesh axis by replica-group stride.  One collective is
+    counted once per execution however the compiler splits it (see
+    ``HloCost.add``), and the TPU's all-reduce-scatter fusion counts as the
+    reduce-scatter it is (see ``ModuleCost._instr_cost``).
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 import re
 from collections import defaultdict
@@ -105,6 +109,9 @@ class CollectiveOp:
     stride: int                  # replica-id stride within a group
     count: float = 1.0           # executions (after trip-count multiply)
     shape: str = ""
+    #: the op's ``channel_id`` while it is summed into the computation
+    #: that runs it once; None after a loop repeats it (see ``HloCost.add``)
+    channel: Optional[int] = None
 
     @property
     def total_bytes(self) -> float:
@@ -130,15 +137,31 @@ class HloCost:
             op_flops={n: v * k for n, v in self.op_flops.items()})
 
     def add(self, other: "HloCost") -> "HloCost":
+        """Both costs, run once each, in one execution of a computation.
+
+        A collective of ``other`` whose ``channel_id`` is already among
+        ``self``'s is the same collective and is not counted again.  XLA
+        gives each collective of a partitioned module its own channel; the
+        TPU compiler splits one all-gather into a chain of async collective
+        fusions (``AsyncCollectiveStart``, ``async_collective_fusion``s
+        overlapped with matmuls, ``AsyncCollectiveDone``), each piece
+        holding an ``all-gather`` of the whole result under the op's
+        channel and ``chain_id``.  The pieces run once each per execution
+        of the computation that holds them, so one channel there is one
+        collective.  ``scaled`` drops the channel, since a loop's
+        iterations are separate executions."""
         of = dict(self.op_flops)
         for n, v in other.op_flops.items():
             of[n] = of.get(n, 0.0) + v
+        seen = {c.channel for c in self.collectives if c.channel is not None}
         return HloCost(
             flops=self.flops + other.flops,
             transcendentals=self.transcendentals + other.transcendentals,
             hbm_bytes=self.hbm_bytes + other.hbm_bytes,
             dot_bytes=self.dot_bytes + other.dot_bytes,
-            collectives=self.collectives + other.collectives,
+            collectives=self.collectives + [
+                c for c in other.collectives
+                if c.channel is None or c.channel not in seen],
             op_flops=of)
 
     def collective_bytes(self) -> Dict[str, float]:
@@ -238,6 +261,8 @@ _ATTR_RE = {
         r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?"),
     "contracting": re.compile(r"lhs_contracting_dims=\{([\d,]*)\}"),
     "metadata_op": re.compile(r'op_name="([^"]*)"'),
+    "channel": re.compile(r"channel_id=(\d+)"),
+    "pairs": re.compile(r"source_target_pairs=\{((?:\{\d+,\d+\},?)*)\}"),
 }
 
 
@@ -373,7 +398,17 @@ def _collective_wire_bytes(kind: str, out_bytes: float, n: int) -> float:
 
 
 def _parse_groups(line: str) -> Tuple[int, int]:
-    """-> (group_size, stride). stride 1 == innermost mesh axis."""
+    """-> (group_size, stride). stride 1 == innermost mesh axis.  A
+    collective-permute's group is the devices of its source-target pairs,
+    its stride the shortest hop between a source and its target."""
+    m = _ATTR_RE["pairs"].search(line)
+    if m:
+        pairs = [tuple(map(int, p)) for p in
+                 re.findall(r"\{(\d+),(\d+)\}", m.group(1))]
+        hops = [abs(t - s) for s, t in pairs if t != s]
+        if not hops:
+            return 1, 1
+        return len({d for p in pairs for d in p}), min(hops)
     m = _ATTR_RE["groups_explicit"].search(line)
     if m:
         ids = [int(x) for x in m.group(1).split(",")]
@@ -407,6 +442,36 @@ def _collective_kind(opcode: str) -> Optional[str]:
         if opcode == base or opcode == base + "-start":
             return base
     return None
+
+
+def _tuple_elements(shape: str) -> List[str]:
+    """The top-level elements of a tuple shape ``(a, b, ...)``; a shape
+    that is not a tuple is its own one element."""
+    if not shape.startswith("("):
+        return [shape]
+    out, depth, start = [], 0, 1
+    for i, ch in enumerate(shape):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                out.append(shape[start:i])
+                break
+        elif ch == "," and depth == 1:
+            out.append(shape[start:i])
+            start = i + 1
+    return [e.strip() for e in out]
+
+
+def _collective_result(instr: Instruction) -> str:
+    """The shape a collective delivers.  An async ``-start`` whose shape is
+    a tuple holds (operand, result, context...): the result is the
+    second element, and the operand and context move nothing more."""
+    parts = _tuple_elements(instr.shape)
+    if instr.opcode.endswith("-start") and len(parts) > 1:
+        return parts[1]
+    return instr.shape
 
 
 class ModuleCost:
@@ -443,11 +508,13 @@ class ModuleCost:
         op = instr.opcode
         kind = _collective_kind(op)
         if kind is not None:
-            out_b = shape_bytes(instr.shape)
+            out_b = shape_bytes(_collective_result(instr))
             size, stride = _parse_groups(instr.line)
             wire = _collective_wire_bytes(kind, out_b, size)
+            ch = _ATTR_RE["channel"].search(instr.line)
             return HloCost(hbm_bytes=0.0, collectives=[
-                CollectiveOp(kind, wire, size, stride, 1.0, instr.shape)])
+                CollectiveOp(kind, wire, size, stride, 1.0, instr.shape,
+                             int(ch.group(1)) if ch else None)])
         if op.endswith("-done") or op in ("after-all",):
             return HloCost()
 
@@ -455,11 +522,22 @@ class ModuleCost:
             m = _ATTR_RE["calls"].search(instr.line)
             inner = self.cost(m.group(1)) if m else HloCost()
             io_bytes = shape_bytes(instr.shape) + self._operand_bytes(instr, comp)
+            colls = inner.collectives
+            if m and m.group(1).startswith("all-reduce-scatter"):
+                # the TPU compiler's reduce-scatter: an all-reduce whose
+                # result the fusion slices to this chip's 1/n, moved as a
+                # reduce-scatter of the fusion's result
+                out_b = shape_bytes(instr.shape)
+                colls = [dataclasses.replace(
+                    c, kind="reduce-scatter",
+                    wire_bytes=_collective_wire_bytes(
+                        "reduce-scatter", out_b, c.group_size))
+                    if c.kind == "all-reduce" else c for c in colls]
             return HloCost(flops=inner.flops,
                            transcendentals=inner.transcendentals,
                            hbm_bytes=io_bytes,
                            dot_bytes=inner.dot_bytes,
-                           collectives=inner.collectives,
+                           collectives=colls,
                            op_flops=inner.op_flops)
         if op == "while":
             body = _ATTR_RE["body"].search(instr.line)

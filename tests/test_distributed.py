@@ -146,3 +146,70 @@ def test_collective_atom_and_walker_agree():
     assert abs(total - wire) / wire < 0.05, (total, wire)
     print("OK atom bytes == walker bytes")
     """)
+
+
+#: the tensor-parallel serving check below: (phase, tolerance on
+#: ||served - reference|| / ||reference|| of the logits).  The weights are
+#: the same bf16 numbers on both sides; the served model rounds each
+#: activation to bf16 (2^-8 relative), which over 2 layers reads about 0.01
+#: here, so 0.04 leaves room, and a float8 pass (~0.15) or a missing
+#: exchange between chips (order 1) is far outside.  Decode's logits come
+#: through the cache the sharded prefill wrote, so they get the same room.
+TP_PHASES = [("prefill", 0.04), ("decode", 0.04)]
+
+
+@pytest.mark.subproc
+@pytest.mark.parametrize("phase,tol", TP_PHASES, ids=[p for p, _ in TP_PHASES])
+def test_tensor_parallel_serving_matches_plain_reference(phase, tol):
+    """A small Qwen2 with 4 KV heads served by ``Engine`` on a 1 x 4 mesh
+    (prefill under ``PREFILL_RULES``, then decode through its cache under
+    ``DECODE_RULES``), against the benchmark's plain float32 forward
+    (``bench/reference/qwen2.py``) on the same seeded weights."""
+    _run(f"""
+    import sys
+    sys.path.insert(0, {ROOT!r})
+    import jax, numpy as np
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+    from bench.archs import qwen2 as arch
+    from bench.reference import qwen2 as ref
+    from repro.configs.run import SERVE_RUN
+    from repro.launch.mesh import make_mesh
+    from repro.models.model_zoo import build_model
+    from repro.parallel.sharding import DECODE_RULES, make_rules
+    from repro.serve.engine import Engine
+
+    m = dict(hidden_size=128, intermediate_size=256,
+             num_attention_heads=8, num_key_value_heads=4,
+             num_hidden_layers=2, vocab_size=512, rms_norm_eps=1e-6,
+             rope_theta=1e6, tie_word_embeddings=False)
+    model = build_model(arch.model_config(
+        {{"registry": "qwen2-7b", "config": m}}), SERVE_RUN)
+    mesh = make_mesh((1, 4), ("data", "model"), devices=jax.devices()[:4])
+    specs = model.param_specs(make_rules(mesh, DECODE_RULES))
+    w = ref.make_weights(m, jax.random.key(2 ** 31 + 15), shardings=jax.tree.map(
+        lambda sp: NamedSharding(mesh, sp), specs))
+    B, S, STEPS = 4, 16, 4
+    prompts = np.random.default_rng(15).integers(0, 512, (B, S),
+                                                 dtype=np.int32)
+    eng = Engine(model, w, batch_slots=B, max_len=S + STEPS, mesh=mesh,
+                 keep_logits=True)
+    tok, cache, lg = eng.prefill(w, {{"tokens": jnp.asarray(prompts)}})
+    served, seq = [np.asarray(lg, np.float32)[:, 0]], [np.asarray(tok)]
+    if {phase!r} == "decode":
+        served = []
+        for _ in range(STEPS):
+            tok, cache, lg = eng.decode(w, tok, cache)
+            served.append(np.asarray(lg, np.float32)[:, 0])
+            seq.append(np.asarray(tok))
+        tokens = np.concatenate([prompts] + seq[:-1], 1)
+        pos = np.arange(S, S + STEPS, dtype=np.int32)
+    else:
+        tokens, pos = prompts, np.array([S - 1], np.int32)
+    want = np.asarray(ref.make_forward(m)(w, jnp.asarray(tokens),
+                                          jnp.asarray(pos))[0])
+    got = np.stack(served, 1)
+    err = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert err <= {tol}, err
+    print("OK tensor-parallel", {phase!r}, err)
+    """)

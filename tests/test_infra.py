@@ -172,6 +172,103 @@ def test_walker_trip_count_and_collectives_synthetic():
     assert coll["all-reduce"] == pytest.approx(2 * 256 * 3 / 4)
 
 
+# One layer of a tensor-parallel step as the TPU compiler writes it, cut to
+# its collectives, run 3 times by a loop: an all-gather split into a chain
+# of async collective fusions that share one channel (each piece holding a
+# gather of the whole result), an all-reduce-scatter fusion, and an async
+# collective-permute whose shape holds (operand, result, context).
+CHAIN_HLO = """
+HloModule chain
+
+%ag_start (p0: f32[2,8]) -> (f32[2,8], f32[8,8], u32[]) {
+  %p0 = f32[2,8] parameter(0)
+  %ag.1 = f32[8,8] all-gather(%p0), channel_id=7, replica_groups=[1,4]<=[4], dimensions={0}, frontend_attributes={chain_id="0"}
+  ROOT %cc.1 = (f32[2,8], f32[8,8], u32[]) custom-call(%ag.1), custom_call_target="AsyncCollectiveStart"
+}
+
+%async_collective_fusion.1 (p0: f32[2,8], p1: f32[8,8], w: f32[8,8]) -> (f32[2,8], f32[8,8], f32[2,8]) {
+  %p0 = f32[2,8] parameter(0)
+  %p1 = f32[8,8] parameter(1)
+  %w = f32[8,8] parameter(2)
+  %d = f32[2,8] dot(%p0, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %ag.2 = f32[8,8] all-gather(%p0), channel_id=7, replica_groups=[1,4]<=[4], dimensions={0}, frontend_attributes={chain_id="0"}
+  ROOT %t = (f32[2,8], f32[8,8], f32[2,8]) tuple(%p0, %ag.2, %d)
+}
+
+%ag_done (p0: f32[2,8], p1: f32[8,8]) -> f32[8,8] {
+  %p0 = f32[2,8] parameter(0)
+  %p1 = f32[8,8] parameter(1)
+  %ag.3 = f32[8,8] all-gather(%p0), channel_id=7, replica_groups=[1,4]<=[4], dimensions={0}, frontend_attributes={chain_id="0"}
+  ROOT %cc.2 = f32[8,8] custom-call(%p0, %p1, %ag.3), custom_call_target="AsyncCollectiveDone"
+}
+
+%add (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %s = f32[] add(%x, %y)
+}
+
+%all-reduce-scatter.3 (input: f32[8,8]) -> f32[2,8] {
+  %input = f32[8,8] parameter(0)
+  %ar = f32[8,8] all-reduce(%input), channel_id=9, replica_groups={{0,1,2,3}}, to_apply=%add
+  %zero = s32[] constant(0)
+  ROOT %ds = f32[2,8] dynamic-slice(%ar, %zero, %zero), dynamic_slice_sizes={2,8}
+}
+
+%body (p: (s32[], f32[2,8], f32[8,8])) -> (s32[], f32[2,8], f32[8,8]) {
+  %p = (s32[], f32[2,8], f32[8,8]) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %x = f32[2,8] get-tuple-element(%p), index=1
+  %w = f32[8,8] get-tuple-element(%p), index=2
+  %f.1 = (f32[2,8], f32[8,8], u32[]) fusion(%x), kind=kCustom, calls=%ag_start
+  %x1 = f32[2,8] get-tuple-element(%f.1), index=0
+  %g1 = f32[8,8] get-tuple-element(%f.1), index=1
+  %f.2 = (f32[2,8], f32[8,8], f32[2,8]) fusion(%x1, %g1, %w), kind=kCustom, calls=%async_collective_fusion.1
+  %x2 = f32[2,8] get-tuple-element(%f.2), index=0
+  %g2 = f32[8,8] get-tuple-element(%f.2), index=1
+  %full = f32[8,8] fusion(%x2, %g2), kind=kCustom, calls=%ag_done
+  %rs = f32[2,8] fusion(%full), kind=kCustom, calls=%all-reduce-scatter.3
+  %cp = (f32[2,8], f32[2,8], u32[], u32[]) collective-permute-start(%rs), channel_id=11, source_target_pairs={{0,1},{1,2},{2,3},{3,0}}
+  %y = f32[2,8] collective-permute-done(%cp)
+  %one = s32[] constant(1)
+  %ip = s32[] add(%i, %one)
+  ROOT %t = (s32[], f32[2,8], f32[8,8]) tuple(%ip, %y, %w)
+}
+
+%cond (p: (s32[], f32[2,8], f32[8,8])) -> pred[] {
+  %p = (s32[], f32[2,8], f32[8,8]) parameter(0)
+  %i = s32[] get-tuple-element(%p), index=0
+  %n = s32[] constant(3)
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+ENTRY %main (a: f32[2,8], w: f32[8,8]) -> f32[2,8] {
+  %a = f32[2,8] parameter(0)
+  %w = f32[8,8] parameter(1)
+  %z = s32[] constant(0)
+  %init = (s32[], f32[2,8], f32[8,8]) tuple(%z, %a, %w)
+  %loop = (s32[], f32[2,8], f32[8,8]) while(%init), condition=%cond, body=%body
+  ROOT %r = f32[2,8] get-tuple-element(%loop), index=1
+}
+"""
+
+
+def test_walker_counts_chained_gather_and_reduce_scatter_once():
+    """Per trip and chip over 4 chips: one gather of 256 B at 3/4; one
+    reduce-scatter whose 64 B result is a quarter of what it reduces, at
+    64 x 3 (not an all-reduce of 256 B at 2 x 3/4); one permute of its
+    64 B result (the operand and context in the start's tuple move
+    nothing more).  The loop runs 3 trips, each its own execution."""
+    cost = analyze_hlo(CHAIN_HLO)
+    assert cost.collective_bytes() == {"all-gather": 3 * 256 * 3 / 4,
+                                       "reduce-scatter": 3 * 64 * 3,
+                                       "collective-permute": 3 * 64}
+    permute = next(c for c in cost.collectives
+                   if c.kind == "collective-permute")
+    assert (permute.group_size, permute.stride) == (4, 1)
+    assert cost.flops == pytest.approx(3 * 2 * 2 * 8 * 8, abs=3 * 10)
+
+
 # The TPU backend emits matmuls as convolutions; these lines are the shapes
 # and attributes it printed for Qwen2-1.5B's projections and LM head.
 TPU_CONVS = [

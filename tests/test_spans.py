@@ -6,6 +6,7 @@ plane, nested as the replay's phases are, one per phase and none per row.
 """
 import glob
 import os
+import pathlib
 import subprocess
 import sys
 
@@ -138,6 +139,56 @@ def test_n_samples_counts_executed_samples(tmp_path):
     assert "per_sample" not in "".join(fused.to_dict())
 
 
+def _mesh_bound_spans(out):
+    """In a process that sees four CPU devices: a fused replay whose rows
+    carry wire bytes, on a 1 x 4 mesh, traced; prints its span names,
+    whether they nest and follow one another as on one chip, and the
+    wire the report says it burned against the profile's."""
+    import json
+
+    from repro.core.atoms import CollectiveQuant
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 4), ("data", "model"))
+    em = Emulator(compute_tile=TILE, mem_block=BLOCK, mesh=mesh)
+    wpi = CollectiveQuant(n=4).wire_bytes_per_iter
+    prof = _profile([ResourceVector(flops=(1 + i % 2) * FPI,
+                                    hbm_bytes=(1 + i % 2) * BPI,
+                                    ici_bytes={"all-gather": (1 + i % 3) * wpi})
+                     for i in range(16)])
+    em.emulate(prof)                            # builds the program
+    rep = []
+    spans = traced(pathlib.Path(out), lambda: rep.append(em.emulate(prof)))
+    ordered = all(a[1] <= b[0] for a, b in zip(spans[1:], spans[2:]))
+    nested = all(_inside(sp, spans[0]) for sp in spans[1:])
+    print(json.dumps({"names": [n for _, _, n in spans], "ordered": ordered,
+                      "nested": nested, "mode": rep[0].mode,
+                      "emulated_ici": rep[0].emulated_ici_bytes,
+                      "planned_ici": prof.totals.ici_total}))
+
+
+@pytest.mark.subproc
+def test_mesh_bound_fused_emulate_spans(tmp_path):
+    """The mesh-bound fused replay, whose segments carry the collective
+    leg, opens the one-chip replay's spans in the same order, and reports
+    the wire it burned."""
+    import json
+    here = os.path.dirname(os.path.abspath(__file__))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": os.path.join(os.path.dirname(here), "src")}
+    p = subprocess.run([sys.executable, os.path.join(here, "test_spans.py"),
+                        str(tmp_path)], env=env, capture_output=True,
+                       text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-3000:]
+    r = json.loads(p.stdout.strip().splitlines()[-1])
+    assert r["names"] == ["synapse.emulate", "synapse.schedule",
+                          "synapse.segment.launch", "synapse.segment.sync",
+                          "synapse.account"], r
+    assert r["ordered"] and r["nested"], r
+    assert r["mode"] == "fused"
+    assert r["emulated_ici"] == pytest.approx(r["planned_ici"])
+
+
 @pytest.mark.subproc
 def test_import_obs_leaves_jax_out():
     code = ("import sys, repro.obs; "
@@ -150,3 +201,7 @@ def test_import_obs_leaves_jax_out():
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stderr
     assert out.stdout.strip() == "False"
+
+
+if __name__ == "__main__":
+    _mesh_bound_spans(sys.argv[1])

@@ -6,7 +6,10 @@ large for HBM.  These tests compile the Pallas kernels at the sizes the
 atoms and Qwen2-1.5B use, one fused segment program at the emulator's
 default tile and block, the memory leg's programs with the ring a TPU
 gets, and Qwen2-1.5B's decode step, whose profile must count the FLOPs its
-shapes imply (the TPU emits matmuls as convolutions).
+shapes imply (the TPU emits matmuls as convolutions).  Qwen2-7B's
+tensor-parallel prefill, compiled for the four chips of the described
+v5e:2x2, must profile to the wire its sharded step moves, and the
+one-chip Qwen2-1.5B steps to the totals they have always had.
 
 The topology is described inside a module-scoped fixture and never while
 a module is imported: only one process may load the TPU library, and the
@@ -20,21 +23,26 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.sharding import PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - any failure means no TPU compiler
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _sds(sharding, shape, dtype=jnp.float32):
@@ -168,3 +176,110 @@ def test_qwen2_decode_profile_counts_analytic_flops(one_chip):
     want = smoke.analytic_flops(cfg, B, 1, T)
     assert abs(prof.totals.flops / want - 1) <= smoke.FLOPS_REL_TOL, \
         (prof.totals.flops, want)
+
+
+def _abstract(model, shardings):
+    """The model's parameters as shapes, each leaf placed by the sharding
+    of the same path in ``shardings``."""
+    return jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        model.abstract(), shardings)
+
+
+#: Qwen2-1.5B's served steps at the benchmark's sizes (prefill 4 x 2048
+#: into a 2048-slot cache; decode at batch 32 over a 4096-slot cache),
+#: profiled for one described v5e: (FLOPs, HBM bytes).  These are the
+#: totals the profiler gave before it learned the TPU's collective fusions
+#: (the decode pair is also what the chip's own executable profiles to);
+#: a step on one chip has no collective, so they must not move.
+ONE_CHIP_TOTALS = {"prefill": (24414251575580.0, 57463323648.0),
+                   "decode": (121824653084.0, 7198662656.0)}
+
+
+@pytest.mark.parametrize("step", sorted(ONE_CHIP_TOTALS))
+def test_one_chip_qwen2_profiles_keep_their_totals(one_chip, step):
+    from repro.configs import get_config
+    from repro.configs.run import SERVE_RUN
+    from repro.core import profile_compiled
+    from repro.models.model_zoo import build_model
+    from repro.serve.step import make_decode_step, make_prefill_step
+
+    model = build_model(get_config("qwen2-1.5b"), SERVE_RUN)
+    params = _abstract(model, jax.tree.map(lambda _: one_chip,
+                                           model.abstract()))
+    if step == "prefill":
+        compiled = jax.jit(make_prefill_step(model, 2048, with_logits=True)
+                           ).lower(params, {"tokens": _sds(
+                               one_chip, (4, 2048), jnp.int32)}).compile()
+    else:
+        cache = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                             jax.eval_shape(lambda: model.init_cache(32,
+                                                                     4096)))
+        compiled = jax.jit(make_decode_step(model, with_logits=True),
+                           donate_argnums=2).lower(
+            params, _sds(one_chip, (32, 1), jnp.int32), cache).compile()
+    t = profile_compiled(compiled, command=f"qwen2-1.5b:{step}").totals
+    assert (t.flops, t.hbm_bytes, t.ici_bytes) == \
+        (*ONE_CHIP_TOTALS[step], {})
+
+
+def test_qwen2_7b_tp_prefill_profiles_the_wire_it_moves(v5e_2x2):
+    """Qwen2-7B prefill of 4 x 2048 tokens on a 1 x 4 mesh (Megatron
+    tensor parallelism with a sequence-parallel residual), compiled for the
+    four chips of a v5e:2x2.  Per layer and chip, ring model, with
+    ``act`` = B·S·d bf16 and ``n`` = 4:
+
+    * all-gather: 2 x act·(n-1)/n, the residual gathered before attention
+      and before the MLP.  The compiler splits the first into a chain of
+      seven async collective fusions, which count once.
+    * reduce-scatter: 1 x act·(n-1)/n, attention's output projection (an
+      all-reduce-scatter fusion), plus one for the vocabulary-sharded
+      embedding lookup.  The MLP's reduce-scatter the compiler runs as a
+      bidirectional ring of collective-permutes instead.
+    * collective-permute: that ring, 5 partial sums of act/n each, and the
+      query and key weights gathered by 3 permutes each of one chip's
+      shard (d·(hq/n)·hd and d·(hk/n)·hd bf16).
+    * all-to-all: as the HLO has it: the queries (in two halves of the
+      head dim), the keys' rotary halves and the K and V cache between
+      sequence and head shards, B·(S/n)·hd·(hq + 3 hk) bf16 x (n-1)/n.
+
+    Before the fix the chain counted seven times, the reduce-scatter at
+    all-reduce cost and every permute at zero: 12.86 GB against ~6.86."""
+    from jax.sharding import NamedSharding
+
+    from repro.configs import get_config
+    from repro.configs.run import SERVE_RUN
+    from repro.core import profile_compiled
+    from repro.launch.mesh import make_mesh
+    from repro.models.model_zoo import build_model
+    from repro.parallel.sharding import DECODE_RULES, make_rules
+    from repro.serve.engine import Engine
+
+    cfg = get_config("qwen2-7b")
+    model = build_model(cfg, SERVE_RUN)
+    mesh = make_mesh((1, 4), ("data", "model"), devices=v5e_2x2.devices)
+    specs = model.param_specs(make_rules(mesh, DECODE_RULES))
+    params = _abstract(model, jax.tree.map(
+        lambda sp: NamedSharding(mesh, sp), specs))
+    B, S, n = 4, 2048, 4
+    tokens = jax.ShapeDtypeStruct((B, S), jnp.int32,
+                                  sharding=NamedSharding(mesh, P()))
+    engine = Engine(model, params, batch_slots=B, max_len=S, mesh=mesh,
+                    keep_logits=True)
+    compiled = engine.prefill.lower(params, {"tokens": tokens}).compile()
+    prof = profile_compiled(compiled, command="qwen2-7b:prefill", mesh=mesh)
+
+    L, d, hd = cfg.num_layers, cfg.d_model, cfg.head_dim
+    hq, hk, bf16, ring = cfg.num_heads, cfg.num_kv_heads, 2, (n - 1) / n
+    act = B * S * d * bf16
+    want = {
+        "all-gather": 2 * L * act * ring,
+        "reduce-scatter": (L + 1) * act * ring,
+        "collective-permute": L * (5 * act / n
+                                   + 3 * d * (hq + hk) // n * hd * bf16),
+        "all-to-all": L * B * S // n * hd * (hq + 3 * hk) * bf16 * ring,
+    }
+    got = prof.totals.ici_bytes
+    for kind, w in want.items():
+        assert abs(got[kind] / w - 1) <= 0.05, (kind, got[kind], w)
+    assert sum(got.values()) <= 1.05 * sum(want.values()), got
