@@ -173,6 +173,13 @@ class StorageSpec:
         return StorageAtom(calib, block_bytes=self.block_bytes)
 
 
+#: tile-iterations one trip of the compute atom's loop burns: the loop
+#: carries this many independent tiles and multiplies them in one batched
+#: matmul, so its loop control is paid once a group and not once a tile.
+#: On a TPU v5e replaying a Qwen2-1.5B prefill the atom reads 24.0% of the
+#: bf16 peak at one tile a trip, and 67.8%, 73.2% and 72.1% at 4, 8 and 16.
+COMPUTE_GROUP = 8
+
 #: per-shard float32 elements one fused collective iteration moves (the
 #: collective analogue of ComputeAtom.tile / MemoryAtom.block_bytes — the
 #: schedule compiler quantizes wire bytes into repeats of this block)
@@ -276,16 +283,37 @@ class Atom:
 
 
 def compute_burn_body(_, c):
-    """One compute-atom iteration: tile matmul kept bounded by tanh.
-    Shared with the fused schedule compiler so both paths burn
-    identically per iteration."""
+    """One compute-atom trip: a tile matmul kept bounded by tanh, for one
+    ``(tile, tile)`` tile or, batched, for each tile of a ``(group, tile,
+    tile)`` carry.  The tiles of a group are independent, so the MXU
+    pipelines one behind the next.  Shared with the fused schedule
+    compiler so both paths burn identically per iteration."""
     return jnp.tanh(c @ c) * 0.5 + 0.5
 
 
+def _burn_first_tile(i, c):
+    return c.at[0].set(compute_burn_body(i, c[0]))
+
+
+def compute_burn(c, iters):
+    """Burn ``iters`` tile-iterations on the group carry ``c`` that
+    ``compute_operand`` makes: ``iters // group`` trips of the whole group,
+    then ``iters % group`` trips of its first tile alone.  Each loop holds
+    ops and no further loop.  The atom's plan and the fused segment's
+    compute block both run this, so a fused iteration costs exactly what an
+    atom iteration costs."""
+    group = c.shape[0]
+    c = jax.lax.fori_loop(0, jax.lax.div(iters, group), compute_burn_body, c)
+    return jax.lax.fori_loop(0, jax.lax.rem(iters, group), _burn_first_tile,
+                             c)
+
+
 def compute_operand(tile: int):
-    """The burn loop's carry; shared with the schedule compiler so a fused
-    iteration costs exactly what an atom iteration costs."""
-    return jnp.eye(tile, dtype=jnp.float32) * 0.5
+    """The burn loop's carry, ``COMPUTE_GROUP`` tiles; shared with the
+    schedule compiler so a fused iteration costs exactly what an atom
+    iteration costs."""
+    return jnp.broadcast_to(jnp.eye(tile, dtype=jnp.float32) * 0.5,
+                            (COMPUTE_GROUP, tile, tile))
 
 
 def ring_windows(block_bytes: int, platform: str) -> int:
@@ -369,9 +397,7 @@ class ComputeAtom(Atom):
                     return catom_ops.burn(x, iters=iters, tile=tile)
                 self._fn = burn
             else:
-                def burn(x, iters):
-                    return jax.lax.fori_loop(0, iters, compute_burn_body, x)
-                self._fn = jax.jit(burn)
+                self._fn = jax.jit(compute_burn)
         return self._fn
 
     def spec(self) -> ComputeSpec:
@@ -400,6 +426,8 @@ class ComputeAtom(Atom):
     def _build_plan(self, iters: int) -> Plan:
         fn = self._loop_fn()
         x = compute_operand(self.tile)
+        if self.backend == "pallas":       # its kernel burns one tile
+            x = x[0]
         emulated = iters * self.flops_per_iter() * self.efficiency
         return Plan(lambda: fn(x, iters), emulated)
 

@@ -13,7 +13,7 @@ programs instead:
     atoms quantize (``ComputeAtom.iters_for`` / ``MemoryAtom.iters_for`` /
     ``CollectiveQuant.iters_for``, applied to the count-scaled run
     amounts).  A segment executes as ONE jitted ``lax.scan`` over its
-    table — the scan carries the compute tile, the memory leg's ring, and
+    table — the scan carries the compute tiles, the memory leg's ring, and
     (for **mesh-bound** segments, i.e. those with wire-byte rows) a fixed
     shard_map-collective block through every row in order, so the
     cross-sample ordering contract holds *inside* the program and an
@@ -49,8 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.atoms import (CollectiveQuant, ComputeAtom, MemoryAtom,
-                              compute_burn_body, compute_operand,
+from repro.core.atoms import (COMPUTE_GROUP, CollectiveQuant, ComputeAtom,
+                              MemoryAtom, compute_burn, compute_operand,
                               memory_operand, memory_stream_body)
 from repro.core.metrics import ResourceVector
 from repro.obs.spans import span
@@ -90,6 +90,12 @@ class FusedSegment:
     @property
     def compute_iters(self) -> int:
         return int(self.table[:, 0].sum())
+
+    @property
+    def compute_grouped_iters(self) -> int:
+        """The compute iterations burned in full ``COMPUTE_GROUP``-tile
+        trips; the rest of each row runs one tile a trip."""
+        return int((self.table[:, 0] // COMPUTE_GROUP).sum() * COMPUTE_GROUP)
 
     @property
     def memory_iters(self) -> int:
@@ -173,6 +179,8 @@ class CompiledSchedule:
                 "n_barriers": len(self.barriers),
                 "n_rows": self.n_rows,
                 "compute_iters": sum(s.compute_iters for s in self.segments),
+                "compute_grouped_iters": sum(s.compute_grouped_iters
+                                             for s in self.segments),
                 "memory_iters": sum(s.memory_iters for s in self.segments),
                 "collective_iters": sum(s.collective_iters
                                         for s in self.segments)}
@@ -325,7 +333,7 @@ class SegmentRunner:
     def _fn(self, padded_len: int, with_c: bool, with_m: bool,
             with_coll: bool):
         """The segment program ``fn(ring, carry, table) -> (ring, carry)``:
-        ``carry`` holds, in order, the compute tile, the ring's window
+        ``carry`` holds, in order, the compute tiles, the ring's window
         index and the collective block of the legs it runs, ``ring`` the
         memory leg's ring (donated), or ``()`` without a memory leg."""
         key = (padded_len, with_c, with_m, with_coll)
@@ -334,13 +342,12 @@ class SegmentRunner:
             with self._lock:
                 fn = self._fns.get(key)
                 if fn is None:
-                    # one fori_loop block per carried operand, in carry
-                    # order; each burns exactly what the owning atom's
-                    # iteration burns
+                    # one block of loops per carried operand, in carry
+                    # order, each loop directly in the scan's body; each
+                    # burns exactly what the owning atom's iteration burns
                     blocks = []
                     if with_c:
-                        blocks.append(lambda v, row: jax.lax.fori_loop(
-                            0, row[0], compute_burn_body, v))
+                        blocks.append(lambda v, row: compute_burn(v, row[0]))
                     if with_m:
                         m = len(blocks)
                         blocks.append(lambda v, row: jax.lax.fori_loop(

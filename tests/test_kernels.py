@@ -42,7 +42,7 @@ def test_pallas_compute_atom_burns_the_iters_it_reports(iters):
     atom = ComputeAtom(tile=64, backend="pallas")
     plan = atom.plan(iters * atom.flops_per_iter())
     assert plan.amount == iters * atom.flops_per_iter()
-    x = compute_operand(64)
+    x = compute_operand(64)[0]          # the kernel burns one tile
     got = np.asarray(plan.launch())
     np.testing.assert_allclose(
         got, np.asarray(cref.burn_tile(x, iters=iters)), rtol=1e-6)

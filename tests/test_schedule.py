@@ -13,7 +13,9 @@ Pins the ISSUE 2 contracts:
     with exact stats; StorageAtom pre-creates the read scratch file at
     plan time; emulate_many caps its pool at len(profiles);
   * the memory leg walks a ring of blocks, one block a pass, on both
-    paths, and runs exactly the passes the schedule's table counts.
+    paths, and runs exactly the passes the schedule's table counts;
+  * the compute leg burns a group of tiles a loop trip and the rest one
+    tile a trip, on both paths, exactly the iterations the table counts.
 """
 import os
 import threading
@@ -25,7 +27,9 @@ import pytest
 from repro.core import (BarrierStep, Emulator, FusedSegment, Plan, PlanCache,
                         ResourceVector, Sample, StorageAtom, SynapseProfile,
                         compile_schedule)
-from repro.core.atoms import ring_windows
+from repro.core.atoms import (COMPUTE_GROUP, ComputeAtom, compute_burn_body,
+                              compute_operand, ring_windows)
+from repro.core.schedule import CompiledSchedule, SegmentRunner
 from repro.core.emulator import _collapse
 
 # Small tile/block keep device work tiny while staying above the atoms'
@@ -371,3 +375,69 @@ def test_memory_block_must_be_whole_lane_rows():
     assert ring.shape == (R, BLOCK // 512, 128) and int(window) == 0
     with pytest.raises(ValueError, match="128-lane"):
         memory_operand(BLOCK + 4)
+
+
+# ---------------------------------------------------------------------------
+# the compute leg's grouped loop
+# ---------------------------------------------------------------------------
+
+G = COMPUTE_GROUP
+
+
+def _burned(counts, tile):
+    """The compute carry after ``counts[g]`` one-tile iterations of tile g,
+    applied tile by tile from ``compute_operand``."""
+    tiles = []
+    for g, x in enumerate(compute_operand(tile)):
+        for _ in range(counts[g]):
+            x = compute_burn_body(0, x)
+        tiles.append(np.asarray(x))
+    return np.stack(tiles)
+
+
+def _group_counts(n):
+    """Iterations each tile of the group takes when a row of ``n`` runs:
+    ``n // G`` grouped trips for every tile, then ``n % G`` for the first."""
+    return [n // G + (n % G if g == 0 else 0) for g in range(G)]
+
+
+@pytest.mark.parametrize("n", [0, 1, G - 1, G, G + 1, 3 * G + 2])
+def test_compute_leg_burns_exactly_the_planned_tile_iterations(n):
+    """The per-sample plan and a fused segment row of ``n`` iterations
+    each burn ``n`` tile-iterations: ``n // G`` trips of the whole group,
+    then ``n % G`` trips of one tile, with the same arithmetic per tile.
+    A 1x1 tile keeps successive iterations apart in float32 (a 256² tile
+    saturates to all ones within three), so one iteration too many or too
+    few shows."""
+    tile = 1
+    atom = ComputeAtom(tile=tile)
+    plan = atom.plan(n * atom.flops_per_iter())
+    assert plan.amount == n * atom.flops_per_iter()
+    runner = SegmentRunner(tile=tile)
+    seg = runner.launch(FusedSegment(table=np.array([[n, 0, 0]])))
+    want = _burned(_group_counts(n), tile)
+    if n == 0:                        # nothing to burn, nothing dispatched
+        assert plan.launch() is None and seg is None
+        return
+    short = _burned(_group_counts(n - 1), tile)
+    assert np.abs(want - short).max() > 1e-5
+    for got in (np.asarray(plan.launch()), np.asarray(seg[0])):
+        assert got.shape == (G, tile, tile)
+        np.testing.assert_allclose(got, want, rtol=1e-6)
+
+
+def test_describe_counts_the_iterations_of_full_group_trips():
+    """``compute_grouped_iters`` counts, row by row, the iterations burned
+    in full ``G``-tile trips; a row's remainder, and barrier steps, do not
+    count.  ``compute_iters`` is unchanged by the grouping."""
+    sched = CompiledSchedule(steps=[
+        FusedSegment(table=np.array([[G + 1, 0, 0], [G - 1, 3, 0],
+                                     [2 * G, 0, 0]])),
+        BarrierStep(resources=_rv(flops=5 * G * FPI, sw=1 << 20)),
+        FusedSegment(table=np.array([[3 * G + 2, 1, 0], [0, 5, 0]]))])
+    desc = sched.describe()
+    assert desc["compute_iters"] == (G + 1) + (G - 1) + 2 * G + 3 * G + 2
+    assert desc["compute_grouped_iters"] == G + 0 + 2 * G + 3 * G
+    assert [s.compute_grouped_iters for s in sched.segments] == [3 * G, 3 * G]
+    empty = CompiledSchedule(steps=[FusedSegment(table=np.array([[G - 1, 2]]))])
+    assert empty.describe()["compute_grouped_iters"] == 0

@@ -26,6 +26,8 @@ import pytest
 from jax.sharding import PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.atoms import COMPUTE_GROUP
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -93,7 +95,8 @@ def _fused_segment(one_chip):
             runner.block_bytes // 512, 128)
     fn = runner._fn(8, True, True, False)
     return fn.lower(_sds(one_chip, ring),
-                    (_sds(one_chip, (runner.tile, runner.tile)),
+                    (_sds(one_chip, (COMPUTE_GROUP, runner.tile,
+                                     runner.tile)),
                      _sds(one_chip, (), jnp.int32)),
                     _sds(one_chip, (8, 3), jnp.int32)).compile()
 
@@ -110,8 +113,71 @@ def _memory_plan(one_chip):
         _sds(one_chip, (), jnp.int32)).compile()
 
 
+def _compute_plan(one_chip):
+    """The per-sample compute atom's burn program at the default tile."""
+    from repro.core.atoms import ComputeAtom
+    atom = ComputeAtom()
+    return atom._loop_fn().lower(
+        _sds(one_chip, (COMPUTE_GROUP, atom.tile, atom.tile)),
+        _sds(one_chip, (), jnp.int32)).compile()
+
+
 def test_fused_segment_compiles_at_default_tile_and_block(one_chip):
     assert "while" in _fused_segment(one_chip).as_text()
+
+
+def _computations(text):
+    """A compiled module's computations by name: name -> its text."""
+    comps = {}
+    for block in re.split(r"\n(?=%|ENTRY )", text):
+        m = re.match(r"(?:ENTRY )?%([\w.\-]+) ", block)
+        if m:
+            comps[m.group(1)] = block[:block.find("\n}") + 3]
+    return comps
+
+
+def _inlined(comps, name):
+    """A computation's text with that of every fusion it calls, but not
+    the bodies of its loops."""
+    text = comps[name]
+    for callee in re.findall(r"calls=%([\w.\-]+)", text):
+        text += _inlined(comps, callee)
+    return text
+
+
+def _matmul(shape):
+    """A matmul (the TPU emits them as convolutions) with an f32 result of
+    ``shape``."""
+    dims = ",".join(map(str, shape))
+    return re.compile(rf"= f32\[{dims}\]\{{[^}}]*\}} "
+                      rf"(convolution|dot)\(")
+
+
+@pytest.mark.parametrize("program", [_fused_segment, _compute_plan])
+def test_compute_leg_burns_a_tile_group_a_loop_trip(one_chip, program):
+    """The compute leg is a loop whose body multiplies the whole group of
+    ``COMPUTE_GROUP`` tiles in one batched matmul and holds no further
+    loop, and a loop of one-tile trips for a row's remainder, each loop
+    directly in the segment's scan body.  (The benchmark's trace reduction
+    counts such a loop whole to the compute leg.)"""
+    comps = _computations(program(one_chip).as_text())
+    loops = {}                                 # body -> computation holding it
+    for name, text in comps.items():
+        for body in re.findall(r" while\(.*?body=%([\w.\-]+)", text):
+            loops[body] = name
+    t = 256
+    grouped = [b for b in loops
+               if _matmul((COMPUTE_GROUP, t, t)).search(_inlined(comps, b))]
+    single = [b for b in loops if _matmul((t, t)).search(_inlined(comps, b))]
+    assert len(grouped) == 1 and len(single) == 1, (grouped, single)
+    for body in grouped + single:
+        assert " while(" not in _inlined(comps, body), body
+    holder = loops[grouped[0]]
+    assert loops[single[0]] == holder
+    if program is _fused_segment:              # the scan's body holds both
+        assert holder in loops, holder
+    else:
+        assert holder not in loops, holder
 
 
 #: an f32 array in a compiled module's text: its dims and its layout
@@ -141,8 +207,11 @@ def test_memory_leg_ring_stays_in_hbm(one_chip, program):
               if re.search(r" copy(-start)?\(", line) and ring in line]
     assert not copies, copies
     assert "input_output_alias=" in text                 # donated
+    # the compute leg's remainder loop updates its first tile in place too
+    tiles = f"f32[{COMPUTE_GROUP},256,256]"
     passes = [line for line in text.splitlines()
-              if "dynamic-update-slice_fusion" in line and " fusion(" in line]
+              if "dynamic-update-slice_fusion" in line and " fusion(" in line
+              and not line.split(" = ")[1].startswith(tiles)]
     assert passes and all(line.split(" = ")[1].startswith(ring)
                           for line in passes), passes
 
