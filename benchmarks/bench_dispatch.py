@@ -57,9 +57,9 @@ def collective_profile(n_samples: int) -> SynapseProfile:
     Amounts sit at 1–2 collective-quantization iterations, the wire
     analogue of the near-minimum compute/memory amounts above: wall time
     is dominated by dispatch overhead, which is what we measure."""
-    from repro.core.atoms import COLL_BLOCK_ELEMS, collective_factor
+    from repro.core.atoms import CollectiveQuant
     fpi = 2.0 * TILE ** 3
-    wpi = collective_factor("all-reduce", 2) * 4.0 * COLL_BLOCK_ELEMS
+    wpi = CollectiveQuant(n=2).wire_bytes_per_iter
     samples = [Sample(index=i, resources=ResourceVector(
         flops=fpi, ici_bytes={"all-reduce": (1 + i % 2) * wpi}))
         for i in range(n_samples)]

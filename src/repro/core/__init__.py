@@ -8,7 +8,7 @@ profile once (static watcher over compiled HLO, or runtime /proc watchers)
 from repro.core.atoms import (CollectiveAtom, CollectiveQuant,  # noqa
                               CollectiveSpec, ComputeAtom, ComputeSpec,
                               MemoryAtom, MemorySpec, Plan, PlanCache,
-                              StorageAtom, StorageSpec, collective_factor)
+                              StorageAtom, StorageSpec)
 from repro.core.calibrate import HostCalibration, calibrate  # noqa
 from repro.core.emulator import (EmulationReport, Emulator,  # noqa
                                  EmulatorSpec, FleetReport)

@@ -4,21 +4,21 @@ Paper §IV-B, adapted per DESIGN.md §2:
 
   * ComputeAtom    — MXU/FPU matmul burn loop.  ``efficiency`` < 1
                      throttles it exactly like the paper's loop-rate knob
-                     (emulate an app running below peak).  Backends: jnp
-                     (XLA loop) or the Pallas kernel in
-                     ``repro.kernels.compute_atom`` (TPU target).
-  * MemoryAtom     — streams a target byte count through HBM (Pallas:
-                     HBM→VMEM block copies; jnp: a ring of blocks larger than
-                     the chip's on-chip memory, one block read, scaled and
-                     written back in place per iteration).
-  * CollectiveAtom — moves an exact wire-byte count over a mesh axis with
-                     psum/all_gather/ppermute under shard_map (the paper's
+                     (emulate an app running below peak).
+  * MemoryAtom     — streams a target byte count through HBM: a ring of
+                     blocks larger than the chip's on-chip memory, one block
+                     read, scaled and written back in place per iteration.
+  * CollectiveAtom — moves a wire-byte count over a mesh axis as repeated
+                     shard_map'd all-reduces of a fixed block (the paper's
                      "planned" network atom, first-class here).
   * StorageAtom    — block-wise file write/read (libc read/write, unchanged
                      from the paper; block size is the tunable the paper
                      discusses in §IV-E.3).
 
-Atoms expose ``plan(amount) -> Plan`` so the emulator can pre-compile, and
+Each device atom has one program, an XLA loop, and the fused schedule
+compiler (``repro.core.schedule``) runs the same loop body, so an atom
+plan and a fused row burn alike.  Atoms expose ``plan(amount) -> Plan``
+so the emulator can pre-compile, and
 ``seconds(amount, hw)`` — the model cost used by the TTC predictor.  A
 ``Plan`` separates *launch* (enqueue device work, returns an unsynced jax
 value; host plans do the work and return ``None``) from *sync*, so the
@@ -75,7 +75,7 @@ class Plan:
 class PlanCache:
     """Shared, keyed memo of planned atom thunks (fleet emulation).
 
-    Keys are the atom's full plan signature — (kind, backend/config knobs,
+    Keys are the atom's full plan signature — (kind, config knobs,
     quantized amount) — so identical (atom, amount) plans across a fleet of
     concurrently-replayed profiles are built, and their XLA programs traced,
     exactly once.  Builds hold a per-key guard, not the cache-wide lock:
@@ -147,21 +147,17 @@ class PlanCache:
 class ComputeSpec:
     tile: int = 256
     efficiency: float = 1.0
-    backend: str = "jnp"
 
     def build(self, calib=None) -> "ComputeAtom":
-        return ComputeAtom(calib, tile=self.tile, efficiency=self.efficiency,
-                           backend=self.backend)
+        return ComputeAtom(calib, tile=self.tile, efficiency=self.efficiency)
 
 
 @dataclass(frozen=True)
 class MemorySpec:
     block_bytes: int = 1 << 24
-    backend: str = "jnp"
 
     def build(self, calib=None) -> "MemoryAtom":
-        return MemoryAtom(calib, block_bytes=self.block_bytes,
-                          backend=self.backend)
+        return MemoryAtom(calib, block_bytes=self.block_bytes)
 
 
 @dataclass(frozen=True)
@@ -180,46 +176,33 @@ class StorageSpec:
 #: bf16 peak at one tile a trip, and 67.8%, 73.2% and 72.1% at 4, 8 and 16.
 COMPUTE_GROUP = 8
 
-#: per-shard float32 elements one fused collective iteration moves (the
-#: collective analogue of ComputeAtom.tile / MemoryAtom.block_bytes — the
-#: schedule compiler quantizes wire bytes into repeats of this block)
+#: per-shard float32 elements one collective iteration moves (the
+#: collective analogue of ComputeAtom.tile / MemoryAtom.block_bytes — wire
+#: bytes are quantized into repeats of this block)
 COLL_BLOCK_ELEMS = 1 << 15
-
-#: per-shard float32 elements of one barrier-leg collective launch (16 MiB a
-#: chip).  A larger leg runs as repeated launches of this size plus one
-#: remainder: a profiled prefill's leg can move gigabytes, and its whole
-#: operand would not fit beside the application's weights.
-COLL_CHUNK_ELEMS = 1 << 22
-
-
-def collective_factor(kind: str, n: int) -> float:
-    """Ring-model wire bytes per chip per shard byte for a collective over
-    an ``n``-way axis (all-reduce moves ``2*(n-1)/n`` of the shard, …)."""
-    return {"all-reduce": 2.0 * (n - 1) / n,
-            "all-gather": (n - 1) / n,
-            "collective-permute": 1.0}.get(kind, 2.0 * (n - 1) / n)
 
 
 @dataclass(frozen=True)
 class CollectiveQuant:
-    """Picklable wire-byte quantization for fused collective segments.
+    """Picklable wire-byte quantization of the collective atom.
 
     Derivable from a live ``CollectiveAtom`` (``atom.quant()``) *or* from a
     (``CollectiveSpec``, mesh-spec) pair on a host that owns no mesh at all
     (``CollectiveSpec.quant_for``) — which is what lets a meshless parent
     compile schedule tables bit-identical to the ones its mesh-owning fleet
-    workers would compile.  One iteration is one shard_map'd collective call
+    workers would compile.  One iteration is one shard_map'd all-reduce
     over a fixed ``block_elems``-per-shard float32 block, so the emulated
     wire amount is ``iters * wire_bytes_per_iter`` — quantized exactly like
-    compute flops and memory bytes are.
+    compute flops and memory bytes are, on every replay path.
     """
     n: int                               # collective axis size
-    kind: str = "all-reduce"
     block_elems: int = COLL_BLOCK_ELEMS
 
     @property
     def factor(self) -> float:
-        return collective_factor(self.kind, self.n)
+        """Ring-model wire bytes a chip moves per shard byte of an
+        all-reduce over an ``n``-way axis."""
+        return 2.0 * (self.n - 1) / self.n
 
     @property
     def wire_bytes_per_iter(self) -> float:
@@ -235,22 +218,20 @@ class CollectiveQuant:
         return iters * self.wire_bytes_per_iter
 
     def to_dict(self) -> Dict:
-        return {"n": self.n, "kind": self.kind,
-                "block_elems": self.block_elems}
+        return {"n": self.n, "block_elems": self.block_elems}
 
     @staticmethod
     def from_dict(d) -> "CollectiveQuant":
-        return CollectiveQuant(n=int(d["n"]), kind=str(d["kind"]),
+        return CollectiveQuant(n=int(d["n"]),
                                block_elems=int(d["block_elems"]))
 
 
 @dataclass(frozen=True)
 class CollectiveSpec:
     axis: Optional[str] = None           # None: the mesh's last axis
-    kind: str = "all-reduce"
 
     def build(self, mesh) -> "CollectiveAtom":
-        return CollectiveAtom(mesh, axis=self.axis, kind=self.kind)
+        return CollectiveAtom(mesh, axis=self.axis)
 
     def quant_for(self, mesh_spec) -> CollectiveQuant:
         """Quantization for the mesh a *worker* will build from
@@ -261,8 +242,7 @@ class CollectiveSpec:
         if axis not in axes:
             raise ValueError(f"collective axis {axis!r} not in mesh axes "
                              f"{axes}")
-        return CollectiveQuant(n=int(mesh_spec.shape[axes.index(axis)]),
-                               kind=self.kind)
+        return CollectiveQuant(n=int(mesh_spec.shape[axes.index(axis)]))
 
 
 class Atom:
@@ -367,8 +347,7 @@ class ComputeAtom(Atom):
     resource = "flops"
 
     def __init__(self, calib: Optional[HostCalibration] = None,
-                 tile: int = 256, efficiency: float = 1.0,
-                 backend: str = "jnp"):
+                 tile: int = 256, efficiency: float = 1.0):
         """``efficiency``: the paper's loop-rate knob — the profiled
         application's measured efficiency (achieved/peak); the atom burns
         flops/efficiency raw loop flops so wall time matches an application
@@ -376,7 +355,6 @@ class ComputeAtom(Atom):
         self.calib = calib
         self.tile = tile
         self.efficiency = max(efficiency, 1e-6)
-        self.backend = backend
         self._fn: Optional[Callable] = None
         self._fn_lock = threading.Lock()
 
@@ -385,24 +363,12 @@ class ComputeAtom(Atom):
         # Guarded: per-key PlanCache builds run concurrently, and two
         # distinct-key builders must still share one jitted program.
         with self._fn_lock:
-            return self._loop_fn_locked()
-
-    def _loop_fn_locked(self):
-        if self._fn is None:
-            if self.backend == "pallas":
-                from repro.kernels.compute_atom import ops as catom_ops
-                tile = self.tile
-
-                def burn(x, iters):
-                    return catom_ops.burn(x, iters=iters, tile=tile)
-                self._fn = burn
-            else:
+            if self._fn is None:
                 self._fn = jax.jit(compute_burn)
-        return self._fn
+            return self._fn
 
     def spec(self) -> ComputeSpec:
-        return ComputeSpec(tile=self.tile, efficiency=self.efficiency,
-                           backend=self.backend)
+        return ComputeSpec(tile=self.tile, efficiency=self.efficiency)
 
     def flops_per_iter(self) -> float:
         return 2.0 * self.tile ** 3
@@ -420,14 +386,12 @@ class ComputeAtom(Atom):
         # Key on the quantized amount (iters), not the raw flops: amounts
         # that round to the same loop count are the same plan, and the plan
         # reports the amount it actually emulates so sharers agree.
-        key = ("compute", self.backend, self.tile, self.efficiency, iters)
+        key = ("compute", self.tile, self.efficiency, iters)
         return self._cached(key, lambda: self._build_plan(iters))
 
     def _build_plan(self, iters: int) -> Plan:
         fn = self._loop_fn()
         x = compute_operand(self.tile)
-        if self.backend == "pallas":       # its kernel burns one tile
-            x = x[0]
         emulated = iters * self.flops_per_iter() * self.efficiency
         return Plan(lambda: fn(x, iters), emulated)
 
@@ -444,10 +408,9 @@ class MemoryAtom(Atom):
     resource = "hbm_bytes"
 
     def __init__(self, calib: Optional[HostCalibration] = None,
-                 block_bytes: int = 1 << 24, backend: str = "jnp"):
+                 block_bytes: int = 1 << 24):
         self.calib = calib
         self.block_bytes = block_bytes
-        self.backend = backend
         self._fn: Optional[Callable] = None
         self._lock = threading.Lock()
         self._ring = None            # (ring, window), made on first launch
@@ -457,23 +420,14 @@ class MemoryAtom(Atom):
         # builds must share one jitted program
         with self._lock:
             if self._fn is None:
-                if self.backend == "pallas":
-                    from repro.kernels.memory_atom import ops as matom_ops
-
-                    def stream(x, iters):
-                        # the block streams through VMEM in kernel blocks
-                        return matom_ops.stream(x, iters=iters)
-                    self._fn = stream
-                else:
-                    def stream(ring, window, iters):
-                        return jax.lax.fori_loop(0, iters,
-                                                 memory_stream_body,
-                                                 (ring, window))
-                    self._fn = jax.jit(stream, donate_argnums=0)
+                def stream(ring, window, iters):
+                    return jax.lax.fori_loop(0, iters, memory_stream_body,
+                                             (ring, window))
+                self._fn = jax.jit(stream, donate_argnums=0)
             return self._fn
 
     def spec(self) -> MemorySpec:
-        return MemorySpec(block_bytes=self.block_bytes, backend=self.backend)
+        return MemorySpec(block_bytes=self.block_bytes)
 
     def bytes_per_iter(self) -> float:
         return 2.0 * self.block_bytes              # read + write per pass
@@ -487,15 +441,12 @@ class MemoryAtom(Atom):
         iters = self.iters_for(nbytes)
         if iters == 0:
             return Plan.noop()
-        key = ("memory", self.backend, self.block_bytes, iters)
+        key = ("memory", self.block_bytes, iters)
         return self._cached(key, lambda: self._build_plan(iters))
 
     def _build_plan(self, iters: int) -> Plan:
         fn = self._stream_fn()
         amount = iters * self.bytes_per_iter()
-        if self.backend == "pallas":
-            x = jnp.ones((self.block_bytes // 4,), jnp.float32)
-            return Plan(lambda: fn(x, iters), amount)
 
         def launch():
             # the ring is donated: one launch at a time takes it, and the
@@ -521,44 +472,38 @@ class MemoryAtom(Atom):
 class CollectiveAtom(Atom):
     resource = "ici_bytes"
 
-    def __init__(self, mesh=None, axis: Optional[str] = None,
-                 kind: str = "all-reduce"):
+    def __init__(self, mesh=None, axis: Optional[str] = None):
         self.mesh = mesh
         self.axis = axis or (mesh.axis_names[-1] if mesh is not None else None)
-        self.kind = kind
-        self._fns: Dict[int, Callable] = {}
         self._loop_fn: Optional[Callable] = None
+        self._fn: Optional[Callable] = None
+        self._fn_lock = threading.Lock()
 
     def spec(self) -> CollectiveSpec:
-        return CollectiveSpec(axis=self.axis, kind=self.kind)
+        return CollectiveSpec(axis=self.axis)
 
     def quant(self) -> CollectiveQuant:
-        """This atom's fused-segment quantization (needs the mesh)."""
-        return CollectiveQuant(n=self.mesh.shape[self.axis], kind=self.kind)
+        """This atom's wire-byte quantization (needs the mesh)."""
+        return CollectiveQuant(n=self.mesh.shape[self.axis])
 
     def loop_operand(self, block_elems: int = COLL_BLOCK_ELEMS):
-        """The fused scan's collective carry: one fixed block per shard."""
+        """The collective loop's carry: one fixed block per shard."""
         n = self.mesh.shape[self.axis]
         return jnp.ones((n * block_elems,), jnp.float32)
 
     def loop_body(self) -> Callable:
-        """One fused collective iteration: a shape-invariant shard_map'd
-        collective over the fixed block — unlike ``_coll_fn`` (whose
-        all-gather grows its output), the result always matches the input
-        shape so ``lax.scan``/``fori_loop`` can carry it.  Values are kept
-        bounded (psum rescaled by 1/n) because one segment may loop
-        thousands of iterations."""
+        """One collective iteration: a shard_map'd all-reduce over the
+        fixed block whose result matches the input shape, so
+        ``lax.scan``/``fori_loop`` can carry it.  Values are kept bounded
+        (psum rescaled by 1/n) because one leg may loop thousands of
+        iterations.  The atom's plan and the fused segment's collective
+        block both loop over this."""
         if self._loop_fn is None:
             from jax.sharding import PartitionSpec as P
-            mesh, axis, kind = self.mesh, self.axis, self.kind
+            mesh, axis = self.mesh, self.axis
             n = mesh.shape[axis]
 
             def local(x):
-                if kind == "all-gather":
-                    return jax.lax.all_gather(x, axis)[0]
-                if kind == "collective-permute":
-                    perm = [(i, (i + 1) % n) for i in range(n)]
-                    return jax.lax.ppermute(x, axis, perm)
                 return jax.lax.psum(x, axis) * (1.0 / n)
 
             self._loop_fn = jax.shard_map(local, mesh=mesh,
@@ -567,78 +512,37 @@ class CollectiveAtom(Atom):
                                           check_vma=False)
         return self._loop_fn
 
-    def _coll_fn(self, n_elems: int):
-        if n_elems not in self._fns:
-            from jax.sharding import PartitionSpec as P
-            mesh, axis, kind = self.mesh, self.axis, self.kind
-
-            def local(x):
-                if kind == "all-gather":
-                    return jax.lax.all_gather(x, axis)
-                if kind == "collective-permute":
-                    n = mesh.shape[axis]
-                    perm = [(i, (i + 1) % n) for i in range(n)]
-                    return jax.lax.ppermute(x, axis, perm)
-                return jax.lax.psum(x, axis)
-
-            fn = jax.shard_map(local, mesh=mesh, in_specs=P(axis),
-                               out_specs=P(axis) if kind not in
-                               ("all-gather",) else P(axis, None),
-                               check_vma=False)
-            self._fns[n_elems] = jax.jit(fn)
-        return self._fns[n_elems]
-
-    def quantized_wire_bytes(self, n_elems: int) -> float:
-        """The wire bytes an ``n_elems``-operand plan actually emulates
-        (the ring model applied to the quantized per-chip shard) — note
-        tiny amounts clamp UP to one element per shard, so a sub-``4n``-byte
-        leg emulates more than it consumes; the emulator reports this as
-        ``emulated_ici_bytes`` so predicted-vs-emulated stays honest."""
-        n = self.mesh.shape[self.axis]
-        factor = collective_factor(self.kind, n)
-        return factor * 4.0 * n_elems / n
+    def _burn_fn(self):
+        # iters is a traced argument: ONE compilation serves every amount;
+        # guarded like ComputeAtom._loop_fn
+        with self._fn_lock:
+            if self._fn is None:
+                step = self.loop_body()
+                self._fn = jax.jit(lambda x, iters: jax.lax.fori_loop(
+                    0, iters, lambda _, v: step(v), x))
+            return self._fn
 
     def plan(self, wire_bytes: float) -> Plan:
-        if self.mesh is None or wire_bytes <= 0:
+        if self.mesh is None:
             return Plan.noop()
-        n = self.mesh.shape[self.axis]
-        # invert the ring model on the PER-CHIP shard:
-        # wire/chip = factor * shard_bytes  (all-reduce: 2*(n-1)/n)
-        factor = collective_factor(self.kind, n)
-        shard_bytes = wire_bytes / max(factor, 1e-9)
-        n_elems = max(int(shard_bytes / 4) * n, n)
-        n_elems = (n_elems // n) * n or n
-        # Quantized key: amounts rounding to the same shard size share one
-        # plan, and — like ComputeAtom/MemoryAtom — the plan reports the
-        # QUANTIZED amount it emulates, never the builder's raw wire_bytes,
-        # so every cache sharer agrees on what was moved (the emulator
-        # tracks *consumption* from the profile, and *emulation* from this).
-        # Mesh identity is part of the key: a shared cache may serve
-        # emulators on different meshes, and a shard_map is bound to its.
+        quant = self.quant()
+        iters = quant.iters_for(wire_bytes)
+        if iters == 0:
+            return Plan.noop()
+        # Keyed, like ComputeAtom/MemoryAtom, on the quantized amount, and
+        # the plan reports the amount it emulates, so every cache sharer
+        # agrees on what was moved.  Mesh identity is part of the key: a
+        # shared cache may serve emulators on different meshes, and a
+        # shard_map is bound to its.
         mesh_id = (tuple(sorted(self.mesh.shape.items())),
                    tuple(d.id for d in self.mesh.devices.flat))
-        key = ("collective", self.kind, self.axis, mesh_id, n_elems)
-        return self._cached(key, lambda: self._build_plan(n_elems))
+        key = ("collective", self.axis, mesh_id, iters)
+        return self._cached(key, lambda: self._build_plan(iters, quant))
 
-    def _build_plan(self, n_elems: int) -> Plan:
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        n = self.mesh.shape[self.axis]
-        full, rem = divmod(n_elems, COLL_CHUNK_ELEMS * n)
-        # operands are laid out over the mesh, so each chip holds only its
-        # own shard and no launch reshards from one device
-        legs = [(self._coll_fn(size),
-                 jnp.ones((size,), jnp.float32,
-                          device=NamedSharding(self.mesh, P(self.axis))),
-                 reps)
-                for size, reps in ((COLL_CHUNK_ELEMS * n, full), (rem, 1))
-                if size and reps]
-
-        def launch():
-            for fn, x, reps in legs:
-                for _ in range(reps):
-                    out = fn(x)
-            return out
-        return Plan(launch, self.quantized_wire_bytes(n_elems))
+    def _build_plan(self, iters: int, quant: CollectiveQuant) -> Plan:
+        fn = self._burn_fn()
+        x = self.loop_operand(quant.block_elems)
+        return Plan(lambda: fn(x, iters), quant.emulated_bytes(iters))
 
     def seconds(self, wire_bytes: float, hw: HardwareSpec) -> float:
         bw = hw.ici_bw * hw.ici_derate

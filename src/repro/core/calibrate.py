@@ -4,7 +4,7 @@ The paper's compute atom is an assembly matmul loop whose throughput defines
 "the maximum efficiency Synapse can emulate"; equivalently we measure what
 this host actually sustains (matmul FLOP/s, stream bytes/s, file I/O bytes/s)
 once, cache it on disk, and atoms use it to convert a resource amount into
-loop iterations.  On a TPU the same role is played by the Pallas atoms +
+loop iterations.  On a TPU the same role is played by the atoms' XLA loops +
 HardwareSpec peaks.
 """
 from __future__ import annotations

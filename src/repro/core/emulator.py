@@ -10,24 +10,26 @@ sample may *speed up* emulation relative to the original serial execution,
 shrinking with finer sampling (paper Fig. 2) — the granularity experiment
 in benchmarks/ reproduces that effect.
 
-Two execution paths share that contract:
+Both replay modes run through ``Emulator.replay`` and share that contract:
 
-  * **fused** (default, jnp backend): the schedule compiler
-    (``repro.core.schedule``) packs contiguous storage/collective-free runs
-    into iteration tables executed as ONE jitted ``lax.scan`` per segment,
-    so an M-sample profile costs O(storage-segment boundaries) device
-    dispatches instead of O(M × atoms); sample ordering is preserved inside
-    the scan.  Runs with storage or executable-collective legs replay
-    per-sample between segments (the I/O interleave is the point of the
-    barrier).  ``benchmarks/bench_dispatch.py`` measures the win.
-  * **per-sample** (``fused=False``, or pallas backends): one plan per atom
-    per collapsed run.  Identical consecutive samples (a layer scan) are
-    planned once and executed as a single scaled consumption, so compile
-    cost is O(distinct samples).
+  * **fused** (default): the schedule compiler (``repro.core.schedule``)
+    packs contiguous storage-free runs into iteration tables executed as
+    ONE jitted ``lax.scan`` per segment, so an M-sample profile costs
+    O(storage-segment boundaries) device dispatches instead of
+    O(M × atoms); sample ordering is preserved inside the scan.  Runs with
+    storage legs (or, with ``keep_collectives=True``, wire legs) stay
+    barrier steps that replay per-sample between segments (the I/O
+    interleave is the point of the barrier).
+    ``benchmarks/bench_dispatch.py`` measures the win.
+  * **per-sample** (``fused=False``, the tests' reference): every collapsed
+    run is a barrier step, one plan per atom.  Identical consecutive
+    samples (a layer scan) are planned once and executed as a single
+    scaled consumption, so compile cost is O(distinct samples).
 
-Both paths consume the profile's resource vectors in the same order with
+Both modes consume the profile's resource vectors in the same order with
 the same count-scaling, so reported ``consumed`` totals are bit-identical
-(``tests/test_schedule.py`` pins this equivalence).
+(``tests/test_schedule.py`` pins this equivalence), and an atom plan burns
+the loop body a fused row burns, quantized the same way.
 """
 from __future__ import annotations
 
@@ -46,8 +48,9 @@ from repro.core.atoms import (CollectiveAtom, CollectiveSpec, ComputeAtom,
 from repro.core.calibrate import HostCalibration, calibrate
 from repro.core.hardware import HardwareSpec
 from repro.core.metrics import ResourceVector, Sample, SynapseProfile
-from repro.core.schedule import (CompiledSchedule, FusedSegment,
-                                 SegmentRunner, compile_schedule)
+from repro.core.schedule import (BarrierStep, CompiledSchedule,
+                                 FusedSegment, SegmentRunner,
+                                 compile_schedule)
 from repro.obs.spans import span
 
 #: fleet backends ``emulate_many``/``run_fleet`` accept (see ``repro.fleet``
@@ -79,17 +82,12 @@ class EmulationReport:
     mode: str = "per_sample"             # "fused" | "per_sample"
     n_dispatches: int = 0                # device dispatches issued
     #: executed wire legs (fused rows / barrier launches), counted the same
-    #: on every path — fused, barrier fallback, and fleet workers — for
-    #: legs of at least one quantization iteration.  Below that the paths
-    #: quantize at different granularities and honestly diverge: a fused
-    #: row rounds sub-half-block legs to a no-op (like compute/memory
-    #: rows), while CollectiveAtom.plan clamps up to one element per shard
-    #: (tests/test_collectives_fused.py pins both).
+    #: way on every path — fused, barrier, per-sample and fleet workers: a
+    #: leg that quantizes to zero whole blocks is a no-op and not counted
     n_collective_dispatches: int = 0
-    #: wire bytes actually moved after quantization — tiny legs clamp UP
-    #: (CollectiveAtom pads sub-4n-byte amounts to one element per shard),
-    #: so this can exceed consumed.ici_total; comparing predicted vs
-    #: emulated must use this, not the profile amount
+    #: wire bytes actually moved after quantization to whole blocks, the
+    #: same on every path; comparing predicted vs emulated must use this,
+    #: not the profile amount
     emulated_ici_bytes: float = 0.0
 
     def summary(self) -> Dict:
@@ -345,8 +343,7 @@ class EmulatorSpec:
     speed: float = 1.0
 
     def build(self, mesh=None) -> "Emulator":
-        em = Emulator(calib=self.calib, backend=self.compute.backend,
-                      compute_tile=self.compute.tile,
+        em = Emulator(calib=self.calib, compute_tile=self.compute.tile,
                       mem_block=self.memory.block_bytes,
                       storage_block=self.storage.block_bytes,
                       efficiency=self.compute.efficiency, speed=self.speed)
@@ -358,7 +355,7 @@ class EmulatorSpec:
 
 class Emulator:
     def __init__(self, calib: Optional[HostCalibration] = None, mesh=None,
-                 backend: str = "jnp", compute_tile: int = 256,
+                 compute_tile: int = 256,
                  mem_block: int = 1 << 24, storage_block: int = 1 << 20,
                  efficiency: float = 1.0, speed: float = 1.0,
                  plan_cache: Optional[PlanCache] = None):
@@ -374,17 +371,13 @@ class Emulator:
         The host calibration is measured on first use of ``calib``."""
         self._calib = calib
         self.compute = ComputeAtom(calib, tile=compute_tile,
-                                   efficiency=efficiency, backend=backend)
-        self.memory = MemoryAtom(calib, block_bytes=mem_block,
-                                 backend=backend)
+                                   efficiency=efficiency)
+        self.memory = MemoryAtom(calib, block_bytes=mem_block)
         self.storage = StorageAtom(calib, block_bytes=storage_block)
         self.collective = CollectiveAtom(mesh) if mesh is not None else None
         self.speed = speed
         self.plan_cache = None
         self._fleet_lock = threading.Lock()
-        # Fused segments need table-driven loop counts, which the pallas
-        # atom kernels don't take; those backends fall back to per-sample.
-        self._fusable = backend == "jnp"
         self._segments = SegmentRunner(tile=compute_tile,
                                        block_bytes=mem_block,
                                        collective=self.collective)
@@ -533,12 +526,13 @@ class Emulator:
     def replay(self, sched: CompiledSchedule, *, command: str = "",
                planned: Optional[ResourceVector] = None,
                flops_scale: float = 1.0, storage_scale: float = 1.0,
-               mem_scale: float = 1.0, verify: bool = True
-               ) -> EmulationReport:
-        """Execute an already-compiled schedule (fused path).
+               mem_scale: float = 1.0, verify: bool = True,
+               mode: str = "fused") -> EmulationReport:
+        """Execute an already-compiled schedule.
 
-        This is the whole fused replay loop, factored out of ``emulate`` so
-        a schedule compiled in one process can be shipped (see
+        This is the whole replay loop: ``emulate`` runs both of its modes
+        through it (``mode`` names the one in the report), and a schedule
+        compiled in one process can be shipped (see
         ``CompiledSchedule.detach``) and replayed by a fleet worker's own
         emulator with identical consumption accounting: segments run as one
         dispatch each — mesh-bound segments execute their wire rows inside
@@ -592,7 +586,7 @@ class Emulator:
                 coll_dispatches += c
                 emulated_ici += e
         return _report(executed, verify, t_start, command=command,
-                       planned=planned, mode="fused",
+                       planned=planned, mode=mode,
                        n_dispatches=dispatches,
                        n_collective_dispatches=coll_dispatches,
                        emulated_ici_bytes=emulated_ici)
@@ -604,38 +598,24 @@ class Emulator:
             with span("synapse.schedule"):
                 runs = _collapse(profile.samples)
                 t_start = time.perf_counter()
-                sched = None
-                if fused and self._fusable:
+                if fused:
                     sched = compile_schedule(runs, compute=self.compute,
                                              memory=self.memory,
                                              collective=self.collective,
                                              flops_scale=flops_scale,
                                              mem_scale=mem_scale,
                                              speed=self.speed)
-            if sched is not None:
-                rep = self.replay(sched, command=profile.command,
-                                  planned=profile.totals,
-                                  flops_scale=flops_scale,
-                                  storage_scale=storage_scale,
-                                  mem_scale=mem_scale, verify=verify)
-                rep.ttc_s = time.perf_counter() - t_start  # include compile
-                return rep
-            executed: List[List[ResourceVector]] = []
-            dispatches = 0
-            coll_dispatches = 0
-            emulated_ici = 0.0
-            for r, count in runs:
-                rows, d, c, e = self._run_per_sample(
-                    r, count, flops_scale, storage_scale, mem_scale)
-                executed.append(rows)
-                dispatches += d
-                coll_dispatches += c
-                emulated_ici += e
-            return _report(executed, verify, t_start,
-                           command=profile.command, planned=profile.totals,
-                           mode="per_sample", n_dispatches=dispatches,
-                           n_collective_dispatches=coll_dispatches,
-                           emulated_ici_bytes=emulated_ici)
+                else:
+                    sched = CompiledSchedule(steps=[
+                        BarrierStep(resources=r, count=c) for r, c in runs])
+            rep = self.replay(sched, command=profile.command,
+                              planned=profile.totals,
+                              flops_scale=flops_scale,
+                              storage_scale=storage_scale,
+                              mem_scale=mem_scale, verify=verify,
+                              mode="fused" if fused else "per_sample")
+            rep.ttc_s = time.perf_counter() - t_start  # include compile
+            return rep
 
     def emulate_many(self, profiles: Iterable[SynapseProfile], *,
                      flops_scale: float = 1.0, storage_scale: float = 1.0,
@@ -729,10 +709,10 @@ class Emulator:
                 "gating.  Use FleetConfig.process(...) or .remote(...)")
         cfg.check_collect(collect, dag=is_dag)
         if cfg.executor in ("process", "remote"):
-            if not (fused and self._fusable):
+            if not fused:
                 raise ValueError(f"executor={cfg.executor!r} ships compiled "
-                                 "schedules and requires the fused jnp "
-                                 "replay path (fused=True, backend='jnp')")
+                                 "schedules and requires the fused replay "
+                                 "path (fused=True)")
             if cfg.executor == "remote":
                 from repro.fleet.transport.remote import run_remote_fleet
                 return run_remote_fleet(self, profiles, hosts=cfg.hosts,

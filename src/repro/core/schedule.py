@@ -20,14 +20,14 @@ programs instead:
     M-sample profile costs O(storage-segment boundaries) dispatches
     instead of O(M × atoms) — communication-heavy profiles included.
   * runs with a storage leg (host I/O worker interleave) stay
-    ``BarrierStep``s and replay through the legacy per-sample path,
+    ``BarrierStep``s and replay through the atoms' per-sample plans,
     splitting the segments around them — exactly where the ordering
     contract demands a real barrier.  ``keep_collectives=True`` lowers
     wire-byte runs to barrier steps too: the fallback for meshless parents
     that cannot quantize a collective (no mesh, no ``CollectiveQuant``).
 
 Wire-byte quantization is a picklable ``CollectiveQuant`` (axis size +
-kind + block), so a parent with *no mesh at all* compiles tables
+block), so a parent with *no mesh at all* compiles tables
 bit-identical to the ones its mesh-owning fleet workers would compile —
 mesh-bound segments ship through ``detach()``/``rehydrate_schedule`` like
 any other, and the quant rides along for the worker to validate against
@@ -66,21 +66,16 @@ class FusedSegment:
     (the emulator adds them in sequence so consumed totals are
     bit-identical to the per-sample path).  A segment with any nonzero
     collective iters is **mesh-bound**: executing it needs a
-    ``SegmentRunner`` whose emulator owns a mesh.  Legacy two-column
-    tables (pre-collective payloads, hand-built warmup tables) normalize
-    to three columns with a zero wire column.
+    ``SegmentRunner`` whose emulator owns a mesh.
     """
     table: np.ndarray                     # (n_rows, 3) int32
     rows: List[ResourceVector] = field(default_factory=list)
 
     def __post_init__(self):
         t = np.asarray(self.table, dtype=np.int32)
-        if t.ndim != 2 or t.shape[1] not in (2, 3):
-            raise ValueError(f"segment table must be 2-D with 2 or 3 "
-                             f"columns, got shape {t.shape}")
-        if t.shape[1] == 2:
-            t = np.concatenate(
-                [t, np.zeros((t.shape[0], 1), dtype=np.int32)], axis=1)
+        if t.ndim != 2 or t.shape[1] != 3:
+            raise ValueError(f"segment table must be 2-D with 3 columns, "
+                             f"got shape {t.shape}")
         self.table = t
 
     @property
@@ -112,8 +107,10 @@ class FusedSegment:
 
 @dataclass
 class BarrierStep:
-    """A collapsed run the fused path must replay per-sample: it carries a
-    storage leg (I/O worker interleave) or an executable collective."""
+    """A collapsed run replayed per-sample through the atoms' plans: on
+    the fused path, one that carries a storage leg (I/O worker
+    interleave) or, under ``keep_collectives=True``, a wire leg; on the
+    per-sample path, every run."""
     resources: ResourceVector
     count: int = 1
 
@@ -188,10 +185,8 @@ class CompiledSchedule:
 
 def rehydrate_schedule(payload: Dict) -> CompiledSchedule:
     """Rebuild a ``CompiledSchedule`` from a ``CompiledSchedule.detach()``
-    payload.  Tables and resource vectors come back bit-identical.
-    Version-1 payloads (two-column tables, pre-fused-collectives) load
-    with a zero wire column."""
-    if not isinstance(payload, dict) or payload.get("version") not in (1, 2):
+    payload.  Tables and resource vectors come back bit-identical."""
+    if not isinstance(payload, dict) or payload.get("version") != 2:
         raise ValueError(f"unsupported schedule payload: "
                          f"{payload.get('version') if isinstance(payload, dict) else payload!r}")
     steps: List[ScheduleStep] = []

@@ -78,10 +78,11 @@ def _init(spec):
             table=np.asarray([[1, 1, 0]], dtype=np.int32), rows=[]))
         if em.collective is not None:
             # mesh-bound variant (all three carries) for fused wire rows,
-            # plus a tiny per-sample plan for barrier-fallback bundles
+            # plus the atom's one-block plan, whose program serves every
+            # barrier-fallback wire leg
             em._segments.run(FusedSegment(
                 table=np.asarray([[1, 1, 1]], dtype=np.int32), rows=[]))
-            em.collective.plan(float(1 << 10))()
+            em.collective.plan(em.collective.quant().wire_bytes_per_iter)()
     return em, {"pid": os.getpid(), "devices": jax.device_count(),
                 "mesh": None if spec.mesh is None else list(spec.mesh.shape),
                 "warm": bool(spec.warmup)}

@@ -5,20 +5,22 @@ Fast tests run meshless and pin the compiler/serialization layer: a
 ``CollectiveQuant`` quantizes wire bytes without a live mesh (so a
 meshless parent compiles tables bit-identical to its mesh-owning fleet
 workers'), wire-only runs fuse into three-column segment rows instead of
-``BarrierStep``s, mesh-bound segments survive detach/rehydrate/pickle
-(version-1 two-column payloads still load), and replaying a mesh-bound
-schedule without a mesh fails loudly instead of dropping wire work.
+``BarrierStep``s, mesh-bound segments survive detach/rehydrate/pickle,
+and replaying a mesh-bound schedule without a mesh fails loudly instead
+of dropping wire work.
 
 Mesh tests (``subproc``: they re-exec python with forced host devices,
 like ``test_distributed``) pin the ISSUE 5 acceptance contract: on a
 2-device mesh, fused, per-sample, and ``keep_collectives=True`` barrier
-replay consume bit-identical totals with agreeing collective-dispatch
-counts, cache-sharing plans report the quantized amount (not the first
-builder's raw wire bytes), and tiny legs' clamp-up inflation is surfaced
-as ``emulated_ici_bytes``.  Fleet tests (``slow`` + ``subproc``) round-trip
-a mesh-bound ``ScheduleBundle`` through a real ``ProcessFleet`` and a
-loopback ``RemoteFleet``.
+replay consume bit-identical totals, run the same collective legs and
+report the same ``emulated_ici_bytes``: every path quantizes a leg to
+whole blocks of the one all-reduce program, so a leg under half a block
+is a no-op everywhere, and cache-sharing plans report the quantized
+amount (not the first builder's raw wire bytes).  Fleet tests (``slow`` +
+``subproc``) round-trip a mesh-bound ``ScheduleBundle`` through a real
+``ProcessFleet`` and a loopback ``RemoteFleet``.
 """
+import json
 import os
 import pickle
 import subprocess
@@ -29,8 +31,7 @@ import numpy as np
 import pytest
 
 from repro.core import (CollectiveQuant, CollectiveSpec, Emulator,
-                        ResourceVector, Sample, SynapseProfile,
-                        collective_factor, rehydrate_schedule)
+                        ResourceVector, Sample, SynapseProfile)
 from repro.core.atoms import COLL_BLOCK_ELEMS
 from repro.core.schedule import BarrierStep, FusedSegment
 from repro.fleet import MeshSpec, RemoteFleet, WorkerSpec, bundle_profile
@@ -75,16 +76,18 @@ def _wire_heavy(command="coll-test"):
 # ---------------------------------------------------------------------------
 
 def test_collective_quant_math():
-    q = CollectiveQuant(n=2, kind="all-reduce")
-    assert q.factor == collective_factor("all-reduce", 2) == 1.0
+    q = CollectiveQuant(n=2)
+    assert q.factor == 1.0                      # all-reduce ring: 2(n-1)/n
     assert q.wire_bytes_per_iter == WPI
     assert q.iters_for(4e6) == round(4e6 / WPI)
     assert q.iters_for(0.4 * WPI) == 0          # sub-half-iteration: noop
     assert q.iters_for(-1.0) == 0
     assert q.emulated_bytes(3) == 3 * WPI
-    # kind changes the ring factor, and with it the per-iteration bytes
-    assert CollectiveQuant(n=4, kind="all-gather").factor == 0.75
-    assert CollectiveQuant(n=4, kind="collective-permute").factor == 1.0
+    # the axis size sets the ring factor, and with it the bytes a block
+    assert CollectiveQuant(n=4).factor == 1.5
+    assert CollectiveQuant(n=4).wire_bytes_per_iter == 1.5 * 4.0 * \
+        COLL_BLOCK_ELEMS
+    assert CollectiveQuant(n=8).factor == 1.75
     # n=1 has no wire: every amount quantizes to zero, never divides by 0
     assert CollectiveQuant(n=1).iters_for(1e12) == 0
     assert CollectiveQuant.from_dict(q.to_dict()) == q
@@ -149,22 +152,6 @@ def test_mesh_bound_bundle_roundtrips_through_pickle():
             assert a.resources == b.resources and a.count == b.count
 
 
-def test_version1_payload_loads_with_zero_wire_column():
-    em = _em()
-    payload = em.compile(_profile([_rv(flops=FPI), _rv(hbm=BPI)])).detach()
-    assert payload["version"] == 2
-    legacy = {"version": 1,
-              "steps": [{"kind": "segment",
-                         "table": payload["steps"][0]["table"][:, :2],
-                         "rows": payload["steps"][0]["rows"]}]}
-    back = rehydrate_schedule(legacy)
-    seg = back.segments[0]
-    assert seg.table.shape == (2, 3)
-    assert seg.collective_iters == 0 and not seg.mesh_bound
-    rep = em.replay(back, command="v1")
-    assert rep.consumed == _profile([_rv(flops=FPI), _rv(hbm=BPI)]).totals
-
-
 def test_meshless_replay_of_mesh_bound_schedule_raises():
     em = _em()
     sched = em.compile(_profile([_rv(ici=4e6)]),
@@ -204,8 +191,8 @@ def _run(code: str):
 def test_fused_barrier_and_per_sample_replay_are_equivalent():
     """The ISSUE 5 acceptance contract, on a real 2-device mesh: all three
     replay modes consume bit-identical totals in the same cross-sample
-    order, their collective-leg counts agree, and the fused path does it
-    in O(segments) dispatches."""
+    order, run the same collective legs and emulate the same wire bytes,
+    and the fused path does it in O(segments) dispatches."""
     _run("""
     import jax
     from repro.core import Emulator, ResourceVector, Sample, SynapseProfile
@@ -247,20 +234,26 @@ def test_fused_barrier_and_per_sample_replay_are_equivalent():
     # vs one dispatch per atom per sample on the other paths
     assert fused.n_dispatches == 4, fused.n_dispatches
     assert per_sample.n_dispatches == barrier.n_dispatches == 34
-    # each path emulates (quantized) roughly what the profile planned
-    for rep in (fused, per_sample, barrier):
-        assert abs(rep.emulated_ici_bytes - prof.totals.ici_total) \\
-            < 0.05 * prof.totals.ici_total, rep.emulated_ici_bytes
+    # every path quantizes each leg to the same whole blocks
+    q = em.collective.quant()
+    want = sum(q.emulated_bytes(q.iters_for(r.ici_total)) for r in rvs)
+    assert fused.emulated_ici_bytes == want, fused.emulated_ici_bytes
+    assert per_sample.emulated_ici_bytes == want
+    assert barrier.emulated_ici_bytes == want
+    # ...which is, to within half a block a leg, what the profile planned
+    assert abs(want - prof.totals.ici_total) <= 0.5 * 17 * \\
+        q.wire_bytes_per_iter
     print("OK equivalence")
     """)
 
 
 @pytest.mark.subproc
 def test_plan_cache_sharers_report_quantized_amount_and_tiny_clamp():
-    """ISSUE 5 satellites: two wire amounts quantizing to the same shard
-    share one cached plan and BOTH report the quantized amount (not the
-    first builder's raw bytes); sub-4n-byte legs clamp UP to one element
-    per shard and the plan/report say so."""
+    """ISSUE 5 satellites: two wire amounts quantizing to the same number
+    of blocks share one cached plan and BOTH report the quantized amount
+    (not the first builder's raw bytes); a leg under half a block is a
+    no-op on the per-sample, barrier and fused paths alike, while the
+    profile's bytes stay in ``consumed``."""
     _run("""
     import jax
     from repro.core import (Emulator, PlanCache, ResourceVector, Sample,
@@ -271,38 +264,37 @@ def test_plan_cache_sharers_report_quantized_amount_and_tiny_clamp():
     em = Emulator(compute_tile=64, mem_block=1 << 18, mesh=mesh,
                   plan_cache=PlanCache())
     atom = em.collective
+    q = atom.quant()
 
-    # 4e6+2 and 4e6 both quantize to 1_000_000 elems/shard -> same key;
-    # the first builder's raw amount (4e6+2) must NOT leak to the sharer
+    # 4e6+2 and 4e6 both quantize to 31 blocks -> same key; the first
+    # builder's raw amount (4e6+2) must NOT leak to the sharer
     first = atom.plan(4e6 + 2.0)
     second = atom.plan(4e6)
     assert em.plan_cache.stats()["hits"] == 1
-    assert first.amount == second.amount == 4e6, (first.amount,
-                                                  second.amount)
+    assert q.iters_for(4e6) == 31
+    assert first.amount == second.amount == q.emulated_bytes(31), (
+        first.amount, second.amount)
 
-    # a 10-byte leg clamps up to 1 elem/shard = 8 emulated wire bytes
+    # a 10-byte leg is under half a block: a no-op plan that moves nothing
     tiny = atom.plan(10.0)
-    assert tiny.amount == 8.0, tiny.amount
-    assert tiny() == 8.0
+    assert tiny.amount == 0.0 and tiny.launch() is None
+    assert em.plan_cache.stats()["plans_built"] == 1
 
-    # ...and the replay report surfaces the inflation: consumed keeps the
-    # profile's 10 bytes, emulated reports the quantized 8
+    # ...on every replay path: consumed keeps the profile's 10 bytes,
+    # nothing is emulated and no collective leg runs
     prof = SynapseProfile(command="tiny", samples=[Sample(
         index=0, resources=ResourceVector(
             flops=2.0 * 64 ** 3, ici_bytes={"all-reduce": 10.0}))])
-    rep = em.replay(em.compile(prof, keep_collectives=True), command="tiny")
-    assert rep.consumed.ici_total == 10.0
-    assert rep.emulated_ici_bytes == 8.0
-    assert rep.summary()["emulated_ici_bytes"] == 8.0
-    assert rep.n_collective_dispatches == 1
-
-    # sub-half-block legs quantize to a NO-OP row on the fused path (like
-    # compute/memory rows) — the documented granularity divergence from
-    # the barrier path's clamp-up above; consumed stays bit-identical
+    barrier = em.replay(em.compile(prof, keep_collectives=True),
+                        command="tiny")
     fused_tiny = em.emulate(prof, fused=True)
-    assert fused_tiny.consumed == rep.consumed
-    assert fused_tiny.n_collective_dispatches == 0
-    assert fused_tiny.emulated_ici_bytes == 0.0
+    per_sample = em.emulate(prof, fused=False)
+    for rep in (barrier, fused_tiny, per_sample):
+        assert rep.consumed.ici_total == 10.0
+        assert rep.consumed == barrier.consumed
+        assert rep.emulated_ici_bytes == 0.0
+        assert rep.summary()["emulated_ici_bytes"] == 0.0
+        assert rep.n_collective_dispatches == 0
 
     # a mesh-owning parent bundling for workers of UNKNOWN mesh must ship
     # portable barrier steps, never its own mesh's quantization
@@ -340,26 +332,102 @@ def test_plan_cache_sharers_report_quantized_amount_and_tiny_clamp():
     """)
 
 
-@pytest.mark.subproc
-def test_barrier_leg_runs_in_bounded_sharded_chunks():
-    """A barrier leg larger than one chunk per chip runs as repeated
-    chunk launches plus a remainder, on operands laid out over the mesh,
-    and still reports the whole leg's quantized wire bytes."""
-    _run("""
-    import jax
-    from repro.core import atoms
+#: wire legs of these many blocks: none, under and over half a block, one,
+#: a fraction that rounds up, and a long leg
+LEG_BLOCKS = [0, 0.4, 0.6, 1, 3.5, 1000]
+
+
+@pytest.fixture(scope="module")
+def collective_legs():
+    """Each leg of ``LEG_BLOCKS`` replayed on a 2-device mesh by the
+    per-sample path, a ``keep_collectives=True`` barrier and a fused row,
+    in one subprocess.  The atom's loop body is the real all-reduce plus 1,
+    so from the all-ones block the carry reads 1 + the trips it ran."""
+    out = _run(f"""
+    import json
+    import numpy as np
+    from repro.core import (Emulator, Plan, ResourceVector, Sample,
+                            SynapseProfile)
+    from repro.core.atoms import CollectiveAtom
     from repro.launch.mesh import make_mesh
 
-    atoms.COLL_CHUNK_ELEMS = 8
-    mesh = make_mesh((2,), ("model",))
-    atom = atoms.CollectiveAtom(mesh, axis="model", kind="all-reduce")
-    n_elems = 2 * (3 * 8 + 5)                # 3 full chunks + 5 a chip
-    plan = atom.plan(atom.quantized_wire_bytes(n_elems))
-    assert plan.amount == atom.quantized_wire_bytes(n_elems)
-    assert sorted(atom._fns) == [10, 16]     # chunk and remainder programs
-    out = jax.block_until_ready(plan.launch())
-    assert out.shape == (10,) and len(out.sharding.device_set) == 2
+    class Counting(CollectiveAtom):
+        def loop_body(self):
+            step = super().loop_body()
+            return lambda x: step(x) + 1.0
+
+    em = Emulator(compute_tile=64, mem_block=1 << 18)
+    atom = Counting(make_mesh((2,), ("model",)))
+    em.attach_collective(atom)
+    q = atom.quant()
+    carries = []
+    plan = atom.plan
+
+    def spied(wire):
+        p = plan(wire)
+        def launch():
+            tok = p.launch()
+            if tok is not None:
+                carries.append(tok)
+            return tok
+        return Plan(launch, p.amount)
+    atom.plan = spied
+
+    def carry(tok):
+        return None if tok is None else np.asarray(tok).tolist()
+
+    out = {{}}
+    for blocks in {LEG_BLOCKS!r}:
+        prof = SynapseProfile(command="leg", samples=[Sample(
+            index=0, resources=ResourceVector(
+                ici_bytes={{"all-reduce": blocks * q.wire_bytes_per_iter}}
+                if blocks else {{}}))])
+        legs = {{}}
+        for path in ("per_sample", "barrier", "fused"):
+            carries.clear()
+            if path == "fused":
+                sched = em.compile(prof)
+                rep = em.replay(sched, command="leg")
+                tok = em._segments.launch(sched.segments[0])
+                tok = None if tok is None else tok[-1]
+            else:
+                rep = (em.emulate(prof, fused=False) if path == "per_sample"
+                       else em.replay(em.compile(prof, keep_collectives=True),
+                                      command="leg"))
+                tok = carries[-1] if carries else None
+            legs[path] = {{"emulated": rep.emulated_ici_bytes,
+                          "dispatches": rep.n_collective_dispatches,
+                          "carry": carry(tok)}}
+        out[str(blocks)] = {{"iters": q.iters_for(
+            blocks * q.wire_bytes_per_iter), "wpi": q.wire_bytes_per_iter,
+            "legs": legs}}
+    print(json.dumps(out))
     """)
+    return json.loads(out.strip().splitlines()[-1])
+
+
+@pytest.mark.subproc
+@pytest.mark.parametrize("blocks", LEG_BLOCKS)
+def test_collective_leg_burns_exactly_the_planned_iterations(
+        collective_legs, blocks):
+    """A wire leg of ``blocks`` blocks runs round(blocks) all-reduce trips
+    on the per-sample path, a barrier step and a fused row alike: equal
+    ``emulated_ici_bytes`` and collective-leg counts, and carries that
+    show the same number of trips; a leg under half a block runs none."""
+    got = collective_legs[str(blocks)]
+    iters = got["iters"]
+    assert iters == max(int(round(blocks)), 0)
+    legs = got["legs"]
+    assert set(legs) == {"per_sample", "barrier", "fused"}
+    for path, leg in legs.items():
+        assert leg["emulated"] == iters * got["wpi"], (path, leg)
+        assert leg["dispatches"] == int(iters > 0), (path, leg)
+        if iters == 0:
+            assert leg["carry"] is None, path
+        else:
+            carry = np.asarray(leg["carry"])
+            assert carry.shape == (2 * COLL_BLOCK_ELEMS,), path
+            assert (carry == 1 + iters).all(), (path, carry[:4])
 
 
 # ---------------------------------------------------------------------------

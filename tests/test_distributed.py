@@ -128,22 +128,23 @@ def test_collective_atom_and_walker_agree():
 
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((8,), ("model",))
-    atom = CollectiveAtom(mesh, axis="model", kind="all-reduce")
+    atom = CollectiveAtom(mesh, axis="model")
+    q = atom.quant()
     wire = 8 * 1024 * 1024.0
     thunk = atom.plan(wire)
     got = thunk()
-    # the plan reports the QUANTIZED amount it emulates (whole elements
-    # per shard), within one element-row of the requested wire bytes
-    assert abs(got - wire) / wire < 1e-3
-    n_elems = list(atom._fns.keys())[0]
-    assert got == atom.quantized_wire_bytes(n_elems)
-    # cross-check with the walker on the same program
-    fn = atom._coll_fn(list(atom._fns.keys())[0])
-    n = list(atom._fns.keys())[0]
-    lowered = fn.lower(jax.ShapeDtypeStruct((n,), np.float32))
+    # the plan reports the QUANTIZED amount it emulates: whole blocks,
+    # within half a block of the requested wire bytes
+    assert got == q.emulated_bytes(q.iters_for(wire))
+    assert abs(got - wire) <= 0.5 * q.wire_bytes_per_iter
+    # cross-check with the walker on one iteration of the same body
+    x = atom.loop_operand()
+    lowered = jax.jit(atom.loop_body()).lower(
+        jax.ShapeDtypeStruct(x.shape, x.dtype))
     cost = analyze_hlo(lowered.compile().as_text())
     total = cost.collective_total
-    assert abs(total - wire) / wire < 0.05, (total, wire)
+    assert abs(total - q.wire_bytes_per_iter) \\
+        <= 1e-6 * q.wire_bytes_per_iter, (total, q.wire_bytes_per_iter)
     print("OK atom bytes == walker bytes")
     """)
 

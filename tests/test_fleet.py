@@ -109,7 +109,14 @@ def test_rehydrate_rejects_bad_payloads():
     with pytest.raises(ValueError):
         rehydrate_schedule("not a payload")
     with pytest.raises(ValueError):
-        rehydrate_schedule({"version": 1, "steps": [{"kind": "wat"}]})
+        rehydrate_schedule({"version": 2, "steps": [{"kind": "wat"}]})
+    # only version 2 loads, and a segment table has the three columns
+    with pytest.raises(ValueError):
+        rehydrate_schedule({"version": 1, "steps": []})
+    with pytest.raises(ValueError, match="3 columns"):
+        rehydrate_schedule({"version": 2, "steps": [
+            {"kind": "segment", "table": np.zeros((1, 2), np.int32),
+             "rows": []}]})
 
 
 def test_emulator_spec_roundtrips_through_pickle(tmp_path):

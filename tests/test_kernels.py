@@ -1,75 +1,13 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode), shape/dtype sweeps
-plus hypothesis property tests (assignment deliverable c)."""
+"""The flash-attention Pallas kernel vs its pure-jnp oracle (interpret
+mode): shape/dtype sweeps plus hypothesis property tests."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
 
-from repro.kernels.compute_atom import kernel as ck, ops as cops, ref as cref
 from repro.kernels.flash_attention import (kernel as fk, ops as fops,
                                            ref as fref)
-from repro.kernels.memory_atom import kernel as mk, ops as mops, ref as mref
-
-
-# ---------------------------------------------------------------------------
-# compute atom
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("tile", [8, 64, 128])
-@pytest.mark.parametrize("iters", [1, 3, 17])
-def test_compute_atom_matches_ref(tile, iters):
-    x = jax.random.normal(jax.random.key(0), (tile, tile)) * 0.1
-    got = ck.burn_tile(x, iters=iters, interpret=True)
-    want = cref.burn_tile(x, iters=iters)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
-
-
-def test_compute_atom_ops_flops_accounting():
-    out = cops.burn(iters=4, tile=64)
-    assert out.shape == (64, 64)
-    assert np.isfinite(np.asarray(out)).all()
-    assert cref.flops(64, 4) == 2 * 64 ** 3 * 4
-
-
-@pytest.mark.parametrize("iters", [3, 7])
-def test_pallas_compute_atom_burns_the_iters_it_reports(iters):
-    """The Pallas-backed atom's plan reports ``iters`` tile matmuls, and
-    its output is the reference burn of exactly that many (each extra
-    iteration moves the result by a quarter of the last step)."""
-    from repro.core.atoms import ComputeAtom, compute_operand
-    atom = ComputeAtom(tile=64, backend="pallas")
-    plan = atom.plan(iters * atom.flops_per_iter())
-    assert plan.amount == iters * atom.flops_per_iter()
-    x = compute_operand(64)[0]          # the kernel burns one tile
-    got = np.asarray(plan.launch())
-    np.testing.assert_allclose(
-        got, np.asarray(cref.burn_tile(x, iters=iters)), rtol=1e-6)
-    assert not np.allclose(got, np.asarray(cref.burn_tile(x, iters=iters - 1)),
-                           rtol=1e-6)
-
-
-# ---------------------------------------------------------------------------
-# memory atom
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("n,block", [(256, 64), (1024, 1024), (4096, 512)])
-def test_memory_atom_matches_ref(n, block, dtype):
-    x = jnp.arange(n, dtype=dtype)
-    got = mk.stream_pass(x, block=block, interpret=True)
-    want = mref.stream_pass(x)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), rtol=1e-2)
-
-
-def test_memory_atom_multi_pass():
-    x = jnp.ones((2048,), jnp.float32)
-    out = mops.stream(x, iters=5, block=256)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(x) * 1.0000001 ** 5, rtol=1e-5)
-    assert mref.bytes_moved(2048 * 4, 5) == 2 * 2048 * 4 * 5
 
 
 # ---------------------------------------------------------------------------

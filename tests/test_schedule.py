@@ -171,14 +171,6 @@ def test_empty_profile():
     assert rep.consumed == ResourceVector()
 
 
-def test_pallas_backend_falls_back_to_per_sample():
-    em = Emulator(backend="pallas", compute_tile=TILE, mem_block=BLOCK)
-    assert not em._fusable
-    prof = _profile([_rv(flops=0.0)])        # no device work planned
-    rep = em.emulate(prof, fused=True)
-    assert rep.mode == "per_sample"
-
-
 def test_fleet_fused_matches_single(tmp_path):
     profs = [_alternating(12) for _ in range(3)]
     em = _em()
@@ -317,15 +309,16 @@ def _ring_profile():
 
 
 @pytest.mark.parametrize("fused", [True, False])
-def test_memory_leg_rotates_through_every_window(fused):
-    """Past R passes the ring wraps round: each window gets the passes
-    that fall on it, on the fused and the per-sample path alike, and the
-    next replay carries on from the window the last one stopped at."""
+@pytest.mark.parametrize("n", [1, R - 1, R, R + 1, 2 * R + 3])
+def test_memory_leg_rotates_through_every_window(n, fused):
+    """A replay of ``n`` passes, short of, at and past the ring's R
+    windows: each window gets the passes that fall on it, on the fused and
+    the per-sample path alike, and the next replay carries on from the
+    window the last one stopped at, wrapping round past R."""
     assert R > 1
     em = _em()
-    prof = _ring_profile()
-    n = em.compile(prof).describe()["memory_iters"]
-    assert n > 2 * R
+    prof = _profile([_rv(flops=FPI, hbm=n * BPI)])
+    assert em.compile(prof).describe()["memory_iters"] == n
     em.emulate(prof, fused=fused)
     em.emulate(prof, fused=fused)
     state = em._segments._ring if fused else em.memory._ring
@@ -426,6 +419,35 @@ def test_compute_leg_burns_exactly_the_planned_tile_iterations(n):
         np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
+def _plain_burn(tile, iters):
+    """The group carry after ``iters`` tile-iterations, from a plain numpy
+    float32 loop of the burn's arithmetic on each tile of the group."""
+    out = []
+    for n in _group_counts(iters):
+        c = np.eye(tile, dtype=np.float32) * np.float32(0.5)
+        for _ in range(n):
+            c = np.tanh(c @ c) * np.float32(0.5) + np.float32(0.5)
+        out.append(c)
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("iters", [1, 3, G, G + 1, 2 * G + 1])
+@pytest.mark.parametrize("tile", [8, 64])
+def test_compute_burn_matches_plain_reference(tile, iters):
+    """The atom's plan and a fused row of ``iters`` iterations leave each
+    tile of the group where a plain numpy float32 loop of
+    ``tanh(c @ c) * 0.5 + 0.5`` from ``eye * 0.5`` leaves it."""
+    want = _plain_burn(tile, iters)
+    assert want.dtype == np.float32
+    atom = ComputeAtom(tile=tile)
+    plan = atom.plan(iters * atom.flops_per_iter())
+    row = SegmentRunner(tile=tile).launch(
+        FusedSegment(table=np.array([[iters, 0, 0]])))
+    for got in (np.asarray(plan.launch()), np.asarray(row[0])):
+        assert got.shape == (G, tile, tile)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
 def test_describe_counts_the_iterations_of_full_group_trips():
     """``compute_grouped_iters`` counts, row by row, the iterations burned
     in full ``G``-tile trips; a row's remainder, and barrier steps, do not
@@ -439,5 +461,6 @@ def test_describe_counts_the_iterations_of_full_group_trips():
     assert desc["compute_iters"] == (G + 1) + (G - 1) + 2 * G + 3 * G + 2
     assert desc["compute_grouped_iters"] == G + 0 + 2 * G + 3 * G
     assert [s.compute_grouped_iters for s in sched.segments] == [3 * G, 3 * G]
-    empty = CompiledSchedule(steps=[FusedSegment(table=np.array([[G - 1, 2]]))])
+    empty = CompiledSchedule(
+        steps=[FusedSegment(table=np.array([[G - 1, 2, 0]]))])
     assert empty.describe()["compute_grouped_iters"] == 0

@@ -2,11 +2,12 @@
 
 The TPU compiler refuses what interpret mode and the CPU backend accept: a
 Pallas block larger than VMEM, a slice off the tiling, an executable too
-large for HBM.  These tests compile the Pallas kernels at the sizes the
-atoms and Qwen2-1.5B use, one fused segment program at the emulator's
-default tile and block, the memory leg's programs with the ring a TPU
-gets, and Qwen2-1.5B's decode step, whose profile must count the FLOPs its
-shapes imply (the TPU emits matmuls as convolutions).  Qwen2-7B's
+large for HBM.  These tests compile the flash-attention kernel at
+Qwen2-1.5B's widths, one fused segment program at the emulator's default
+tile and block, the compute and memory legs' programs with the ring a TPU
+gets, the collective atom's program on four chips, and Qwen2-1.5B's
+decode step, whose profile must count the FLOPs its shapes imply (the TPU
+emits matmuls as convolutions).  Qwen2-7B's
 tensor-parallel prefill, compiled for the four chips of the described
 v5e:2x2, must profile to the wire its sharded step moves, and the
 one-chip Qwen2-1.5B steps to the totals they have always had.
@@ -53,23 +54,6 @@ def _sds(sharding, shape, dtype=jnp.float32):
 
 def _mosaic(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
-
-
-def test_compute_atom_compiles_with_traced_iters(one_chip):
-    from repro.kernels.compute_atom import kernel as ck
-    compiled = jax.jit(
-        lambda x, n: ck.burn_tile(x, iters=n, interpret=False)).lower(
-        _sds(one_chip, (256, 256)), _sds(one_chip, (), jnp.int32)).compile()
-    assert _mosaic(compiled)
-
-
-def test_memory_atom_compiles_at_its_vmem_block(one_chip):
-    from repro.kernels.memory_atom import ops as mops
-    compiled = jax.jit(
-        lambda x, n: mops.stream(x, iters=n, interpret=False)).lower(
-        _sds(one_chip, (1 << 22,)),                       # 16 MiB
-        _sds(one_chip, (), jnp.int32)).compile()
-    assert _mosaic(compiled)
 
 
 def test_flash_attention_compiles_at_qwen2_widths(one_chip):
@@ -352,3 +336,25 @@ def test_qwen2_7b_tp_prefill_profiles_the_wire_it_moves(v5e_2x2):
     for kind, w in want.items():
         assert abs(got[kind] / w - 1) <= 0.05, (kind, got[kind], w)
     assert sum(got.values()) <= 1.05 * sum(want.values()), got
+
+
+def test_collective_plan_compiles_for_four_chips(v5e_2x2):
+    """The collective atom's one program, a loop of its all-reduce over the
+    fixed block with a traced trip count, compiles for the four chips of a
+    v5e:2x2 with the all-reduce in the loop's body: one compilation serves
+    every wire amount, as on the fused path."""
+    from jax.sharding import NamedSharding
+
+    from repro.core.atoms import COLL_BLOCK_ELEMS, CollectiveAtom
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 4), ("data", "model"), devices=v5e_2x2.devices)
+    atom = CollectiveAtom(mesh)
+    text = atom._burn_fn().lower(
+        _sds(NamedSharding(mesh, P("model")), (4 * COLL_BLOCK_ELEMS,)),
+        _sds(NamedSharding(mesh, P()), (), jnp.int32)).compile().as_text()
+    comps = _computations(text)
+    bodies = [b for t in comps.values()
+              for b in re.findall(r" while\(.*?body=%([\w.\-]+)", t)]
+    assert len(bodies) == 1, bodies
+    assert "all-reduce" in _inlined(comps, bodies[0])
