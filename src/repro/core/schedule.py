@@ -15,7 +15,7 @@ programs instead:
     amounts).  A segment executes as ONE jitted ``lax.scan`` over its
     table — the scan carries the compute tiles, the memory leg's ring, and
     (for **mesh-bound** segments, i.e. those with wire-byte rows) a fixed
-    shard_map-collective block through every row in order, so the
+    group of shard_map-collective blocks through every row in order, so the
     cross-sample ordering contract holds *inside* the program and an
     M-sample profile costs O(storage-segment boundaries) dispatches
     instead of O(M × atoms) — communication-heavy profiles included.
@@ -49,9 +49,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.atoms import (COMPUTE_GROUP, CollectiveQuant, ComputeAtom,
-                              MemoryAtom, compute_burn, compute_operand,
-                              memory_operand, memory_stream_body)
+from repro.core.atoms import (COLL_GROUP, COMPUTE_GROUP, CollectiveQuant,
+                              ComputeAtom, MemoryAtom, collective_burn,
+                              compute_burn, compute_operand, memory_operand,
+                              memory_stream_body)
 from repro.core.metrics import ResourceVector
 from repro.obs.spans import span
 
@@ -99,6 +100,12 @@ class FusedSegment:
     @property
     def collective_iters(self) -> int:
         return int(self.table[:, 2].sum())
+
+    @property
+    def collective_grouped_iters(self) -> int:
+        """The collective iterations burned in full ``COLL_GROUP``-block
+        trips; the rest of each row runs one block a trip."""
+        return int((self.table[:, 2] // COLL_GROUP).sum() * COLL_GROUP)
 
     @property
     def mesh_bound(self) -> bool:
@@ -180,7 +187,9 @@ class CompiledSchedule:
                                              for s in self.segments),
                 "memory_iters": sum(s.memory_iters for s in self.segments),
                 "collective_iters": sum(s.collective_iters
-                                        for s in self.segments)}
+                                        for s in self.segments),
+                "collective_grouped_iters": sum(s.collective_grouped_iters
+                                                for s in self.segments)}
 
 
 def rehydrate_schedule(payload: Dict) -> CompiledSchedule:
@@ -349,8 +358,8 @@ class SegmentRunner:
                             0, row[1], memory_stream_body, v))
                     if with_coll:
                         coll_step = self.collective.loop_body()
-                        blocks.append(lambda v, row: jax.lax.fori_loop(
-                            0, row[2], lambda _, x: coll_step(x), v))
+                        blocks.append(lambda v, row: collective_burn(
+                            coll_step, v, row[2]))
 
                     def segment(ring, carry, table):
                         state = list(carry)

@@ -18,7 +18,9 @@ whole blocks of the one all-reduce program, so a leg under half a block
 is a no-op everywhere, and cache-sharing plans report the quantized
 amount (not the first builder's raw wire bytes).  Fleet tests (``slow`` +
 ``subproc``) round-trip a mesh-bound ``ScheduleBundle`` through a real
-``ProcessFleet`` and a loopback ``RemoteFleet``.
+``ProcessFleet`` and a loopback ``RemoteFleet``.  On 2- and 4-device
+meshes every path burns a leg's blocks a group a trip and its remainder
+a block a trip.
 """
 import json
 import os
@@ -32,7 +34,7 @@ import pytest
 
 from repro.core import (CollectiveQuant, CollectiveSpec, Emulator,
                         ResourceVector, Sample, SynapseProfile)
-from repro.core.atoms import COLL_BLOCK_ELEMS
+from repro.core.atoms import COLL_BLOCK_ELEMS, COLL_GROUP
 from repro.core.schedule import BarrierStep, FusedSegment
 from repro.fleet import MeshSpec, RemoteFleet, WorkerSpec, bundle_profile
 
@@ -176,9 +178,9 @@ def test_folded_wire_reports_zero_emulated_ici():
 # mesh equivalence (subprocess: needs >=2 forced host devices)
 # ---------------------------------------------------------------------------
 
-def _run(code: str):
+def _run(code: str, devices: int = 2):
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
@@ -333,22 +335,30 @@ def test_plan_cache_sharers_report_quantized_amount_and_tiny_clamp():
 
 
 #: wire legs of these many blocks: none, under and over half a block, one,
-#: a fraction that rounds up, and a long leg
-LEG_BLOCKS = [0, 0.4, 0.6, 1, 3.5, 1000]
+#: a fraction that rounds up, a long leg, and legs of one block short of a
+#: group, one group, and three groups and five blocks
+LEG_BLOCKS = [0, 0.4, 0.6, 1, 3.5, 1000, COLL_GROUP - 1, COLL_GROUP,
+              3 * COLL_GROUP + 5]
+#: the sizes of the mesh each leg is replayed on
+LEG_MESHES = (2, 4)
 
 
 @pytest.fixture(scope="module")
 def collective_legs():
-    """Each leg of ``LEG_BLOCKS`` replayed on a 2-device mesh by the
-    per-sample path, a ``keep_collectives=True`` barrier and a fused row,
-    in one subprocess.  The atom's loop body is the real all-reduce plus 1,
-    so from the all-ones block the carry reads 1 + the trips it ran."""
+    """Each leg of ``LEG_BLOCKS`` replayed on a 2- and a 4-device mesh by
+    the per-sample path, a ``keep_collectives=True`` barrier and a fused
+    row, in one subprocess.  The atom's loop body is the real all-reduce
+    plus 1, so from the all-ones carry each block reads 1 + the trips that
+    all-reduced it; the carry comes back as each block's least and
+    greatest element, shard by shard."""
     out = _run(f"""
     import json
+    import jax
     import numpy as np
     from repro.core import (Emulator, Plan, ResourceVector, Sample,
                             SynapseProfile)
-    from repro.core.atoms import CollectiveAtom
+    from repro.core.atoms import (COLL_BLOCK_ELEMS, COLL_GROUP,
+                                  CollectiveAtom)
     from repro.launch.mesh import make_mesh
 
     class Counting(CollectiveAtom):
@@ -356,53 +366,59 @@ def collective_legs():
             step = super().loop_body()
             return lambda x: step(x) + 1.0
 
-    em = Emulator(compute_tile=64, mem_block=1 << 18)
-    atom = Counting(make_mesh((2,), ("model",)))
-    em.attach_collective(atom)
-    q = atom.quant()
-    carries = []
-    plan = atom.plan
-
-    def spied(wire):
-        p = plan(wire)
-        def launch():
-            tok = p.launch()
-            if tok is not None:
-                carries.append(tok)
-            return tok
-        return Plan(launch, p.amount)
-    atom.plan = spied
-
-    def carry(tok):
-        return None if tok is None else np.asarray(tok).tolist()
+    def carry(tok, n):
+        if tok is None:
+            return None
+        blocks = np.asarray(tok).reshape(n, COLL_GROUP, COLL_BLOCK_ELEMS)
+        return [blocks.min(axis=2).tolist(), blocks.max(axis=2).tolist()]
 
     out = {{}}
-    for blocks in {LEG_BLOCKS!r}:
-        prof = SynapseProfile(command="leg", samples=[Sample(
-            index=0, resources=ResourceVector(
-                ici_bytes={{"all-reduce": blocks * q.wire_bytes_per_iter}}
-                if blocks else {{}}))])
-        legs = {{}}
-        for path in ("per_sample", "barrier", "fused"):
-            carries.clear()
-            if path == "fused":
-                sched = em.compile(prof)
-                rep = em.replay(sched, command="leg")
-                tok = em._segments.launch(sched.segments[0])
-                tok = None if tok is None else tok[-1]
-            else:
-                rep = (em.emulate(prof, fused=False) if path == "per_sample"
-                       else em.replay(em.compile(prof, keep_collectives=True),
-                                      command="leg"))
-                tok = carries[-1] if carries else None
-            legs[path] = {{"emulated": rep.emulated_ici_bytes,
-                          "dispatches": rep.n_collective_dispatches,
-                          "carry": carry(tok)}}
-        out[str(blocks)] = {{"iters": q.iters_for(
-            blocks * q.wire_bytes_per_iter), "wpi": q.wire_bytes_per_iter,
-            "legs": legs}}
+    for n in {LEG_MESHES!r}:
+        em = Emulator(compute_tile=64, mem_block=1 << 18)
+        atom = Counting(make_mesh((n,), ("model",),
+                                  devices=jax.devices()[:n]))
+        em.attach_collective(atom)
+        q = atom.quant()
+        carries = []
+        plan = atom.plan
+
+        def spied(wire, plan=plan, carries=carries):
+            p = plan(wire)
+            def launch():
+                tok = p.launch()
+                if tok is not None:
+                    carries.append(tok)
+                return tok
+            return Plan(launch, p.amount)
+        atom.plan = spied
+
+        for blocks in {LEG_BLOCKS!r}:
+            prof = SynapseProfile(command="leg", samples=[Sample(
+                index=0, resources=ResourceVector(
+                    ici_bytes={{"all-reduce": blocks * q.wire_bytes_per_iter}}
+                    if blocks else {{}}))])
+            legs = {{}}
+            for path in ("per_sample", "barrier", "fused"):
+                carries.clear()
+                if path == "fused":
+                    sched = em.compile(prof)
+                    rep = em.replay(sched, command="leg")
+                    tok = em._segments.launch(sched.segments[0])
+                    tok = None if tok is None else tok[-1]
+                else:
+                    rep = (em.emulate(prof, fused=False)
+                           if path == "per_sample"
+                           else em.replay(em.compile(
+                               prof, keep_collectives=True), command="leg"))
+                    tok = carries[-1] if carries else None
+                legs[path] = {{"emulated": rep.emulated_ici_bytes,
+                              "dispatches": rep.n_collective_dispatches,
+                              "carry": carry(tok, n)}}
+            out[f"{{n}}/{{blocks}}"] = {{
+                "iters": q.iters_for(blocks * q.wire_bytes_per_iter),
+                "wpi": q.wire_bytes_per_iter, "legs": legs}}
     print(json.dumps(out))
-    """)
+    """, devices=max(LEG_MESHES))
     return json.loads(out.strip().splitlines()[-1])
 
 
@@ -410,24 +426,37 @@ def collective_legs():
 @pytest.mark.parametrize("blocks", LEG_BLOCKS)
 def test_collective_leg_burns_exactly_the_planned_iterations(
         collective_legs, blocks):
-    """A wire leg of ``blocks`` blocks runs round(blocks) all-reduce trips
-    on the per-sample path, a barrier step and a fused row alike: equal
-    ``emulated_ici_bytes`` and collective-leg counts, and carries that
-    show the same number of trips; a leg under half a block runs none."""
-    got = collective_legs[str(blocks)]
-    iters = got["iters"]
-    assert iters == max(int(round(blocks)), 0)
-    legs = got["legs"]
-    assert set(legs) == {"per_sample", "barrier", "fused"}
-    for path, leg in legs.items():
-        assert leg["emulated"] == iters * got["wpi"], (path, leg)
-        assert leg["dispatches"] == int(iters > 0), (path, leg)
-        if iters == 0:
-            assert leg["carry"] is None, path
-        else:
-            carry = np.asarray(leg["carry"])
-            assert carry.shape == (2 * COLL_BLOCK_ELEMS,), path
-            assert (carry == 1 + iters).all(), (path, carry[:4])
+    """A wire leg of ``blocks`` blocks burns round(blocks) block-iterations
+    on the per-sample path, a barrier step and a fused row alike, on a 2-
+    and a 4-device mesh: equal ``emulated_ici_bytes`` (iterations x the
+    wire of one block) and collective-leg counts, and carries that show
+    the same trips: ``q`` = iters // ``COLL_GROUP`` trips of each shard's
+    whole group, then ``r`` = iters % ``COLL_GROUP`` trips of its first
+    block, so its first block reads 1 + q + r, every other block 1 + q,
+    and the blocks of a shard add up to ``iters`` trips of one block.  A
+    leg under half a block runs none."""
+    G = COLL_GROUP
+    for n in LEG_MESHES:
+        got = collective_legs[f"{n}/{blocks}"]
+        iters = got["iters"]
+        assert iters == max(int(round(blocks)), 0)
+        assert got["wpi"] == CollectiveQuant(n=n).wire_bytes_per_iter
+        want = np.full((n, G), 1.0 + iters // G)
+        want[:, 0] += iters % G
+        legs = got["legs"]
+        assert set(legs) == {"per_sample", "barrier", "fused"}
+        for path, leg in legs.items():
+            where = (n, path)
+            assert leg["emulated"] == iters * got["wpi"], (where, leg)
+            assert leg["dispatches"] == int(iters > 0), (where, leg)
+            if iters == 0:
+                assert leg["carry"] is None, where
+                continue
+            lo, hi = map(np.asarray, leg["carry"])
+            assert lo.shape == (n, G), where
+            np.testing.assert_array_equal(lo, want, err_msg=str(where))
+            np.testing.assert_array_equal(hi, want, err_msg=str(where))
+            assert ((lo - 1).sum(axis=1) == iters).all(), where
 
 
 # ---------------------------------------------------------------------------

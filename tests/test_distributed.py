@@ -123,28 +123,35 @@ def test_sharded_decode_matches_single_device():
 def test_collective_atom_and_walker_agree():
     _run("""
     import jax, numpy as np
-    from repro.core.atoms import CollectiveAtom
+    from repro.core.atoms import (COLL_BLOCK_ELEMS, COLL_GROUP,
+                                  CollectiveAtom)
     from repro.core.hlo_analysis import analyze_hlo
 
     from repro.launch.mesh import make_mesh
-    mesh = make_mesh((8,), ("model",))
-    atom = CollectiveAtom(mesh, axis="model")
-    q = atom.quant()
-    wire = 8 * 1024 * 1024.0
-    thunk = atom.plan(wire)
-    got = thunk()
-    # the plan reports the QUANTIZED amount it emulates: whole blocks,
-    # within half a block of the requested wire bytes
-    assert got == q.emulated_bytes(q.iters_for(wire))
-    assert abs(got - wire) <= 0.5 * q.wire_bytes_per_iter
-    # cross-check with the walker on one iteration of the same body
-    x = atom.loop_operand()
-    lowered = jax.jit(atom.loop_body()).lower(
-        jax.ShapeDtypeStruct(x.shape, x.dtype))
-    cost = analyze_hlo(lowered.compile().as_text())
-    total = cost.collective_total
-    assert abs(total - q.wire_bytes_per_iter) \\
-        <= 1e-6 * q.wire_bytes_per_iter, (total, q.wire_bytes_per_iter)
+    for n in (2, 4, 8):
+        mesh = make_mesh((n,), ("model",), devices=jax.devices()[:n])
+        atom = CollectiveAtom(mesh, axis="model")
+        q = atom.quant()
+        wire = 8 * 1024 * 1024.0
+        thunk = atom.plan(wire)
+        got = thunk()
+        # the plan reports the QUANTIZED amount it emulates: whole blocks,
+        # within half a block of the requested wire bytes
+        assert got == q.emulated_bytes(q.iters_for(wire))
+        assert abs(got - wire) <= 0.5 * q.wire_bytes_per_iter
+        # cross-check with the walker on one trip of the same body: a
+        # single-block trip moves one iteration's wire, a group trip (the
+        # loop's whole carry) COLL_GROUP iterations'
+        x = atom.loop_operand()
+        assert x.shape == (n * COLL_GROUP * COLL_BLOCK_ELEMS,)
+        for elems, iters in ((n * COLL_BLOCK_ELEMS, 1),
+                             (x.size, COLL_GROUP)):
+            lowered = jax.jit(atom.loop_body()).lower(
+                jax.ShapeDtypeStruct((elems,), x.dtype))
+            cost = analyze_hlo(lowered.compile().as_text())
+            total = cost.collective_total
+            want = iters * q.wire_bytes_per_iter
+            assert abs(total - want) <= 1e-6 * want, (n, iters, total, want)
     print("OK atom bytes == walker bytes")
     """)
 

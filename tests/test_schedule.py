@@ -15,7 +15,10 @@ Pins the ISSUE 2 contracts:
   * the memory leg walks a ring of blocks, one block a pass, on both
     paths, and runs exactly the passes the schedule's table counts;
   * the compute leg burns a group of tiles a loop trip and the rest one
-    tile a trip, on both paths, exactly the iterations the table counts.
+    tile a trip, on both paths, exactly the iterations the table counts;
+  * the schedule counts the collective iterations that run in full
+    groups of blocks (the trips themselves run on a mesh, in
+    ``test_collectives_fused``).
 """
 import os
 import threading
@@ -27,8 +30,9 @@ import pytest
 from repro.core import (BarrierStep, Emulator, FusedSegment, Plan, PlanCache,
                         ResourceVector, Sample, StorageAtom, SynapseProfile,
                         compile_schedule)
-from repro.core.atoms import (COMPUTE_GROUP, ComputeAtom, compute_burn_body,
-                              compute_operand, ring_windows)
+from repro.core.atoms import (COLL_GROUP, COMPUTE_GROUP, ComputeAtom,
+                              compute_burn_body, compute_operand,
+                              ring_windows)
 from repro.core.schedule import CompiledSchedule, SegmentRunner
 from repro.core.emulator import _collapse
 
@@ -464,3 +468,29 @@ def test_describe_counts_the_iterations_of_full_group_trips():
     empty = CompiledSchedule(
         steps=[FusedSegment(table=np.array([[G - 1, 2, 0]]))])
     assert empty.describe()["compute_grouped_iters"] == 0
+
+
+def test_describe_counts_the_block_iterations_of_full_collective_groups():
+    """``collective_grouped_iters`` counts, row by row, the iterations
+    burned in full ``COLL_GROUP``-block trips: none of a row under one
+    group, all of a row of one group, ``q`` groups of a row of ``q``
+    groups and ``r`` blocks.  ``collective_iters`` is unchanged by the
+    grouping, and a schedule with no wire reads 0."""
+    C = COLL_GROUP
+    sched = CompiledSchedule(steps=[
+        FusedSegment(table=np.array([[1, 0, C - 1], [0, 2, C],
+                                     [3, 0, 3 * C + 5]])),
+        BarrierStep(resources=_rv(ici=4e6, sw=1 << 20)),
+        FusedSegment(table=np.array([[0, 0, 2 * C + 1], [4, 0, 0]]))])
+    desc = sched.describe()
+    assert desc["collective_iters"] == (C - 1) + C + (3 * C + 5) + 2 * C + 1
+    assert desc["collective_grouped_iters"] == 0 + C + 3 * C + 2 * C
+    assert [s.collective_grouped_iters for s in sched.segments] == \
+        [4 * C, 2 * C]
+    short = CompiledSchedule(
+        steps=[FusedSegment(table=np.array([[G, 2, C - 1]]))])
+    assert short.describe()["collective_grouped_iters"] == 0
+    no_wire = _em().compile(_profile([_rv(flops=3 * FPI, hbm=BPI),
+                                      _rv(flops=FPI, ici=4e6)]))
+    assert no_wire.describe()["collective_iters"] == 0
+    assert no_wire.describe()["collective_grouped_iters"] == 0

@@ -5,12 +5,12 @@ Pallas block larger than VMEM, a slice off the tiling, an executable too
 large for HBM.  These tests compile the flash-attention kernel at
 Qwen2-1.5B's widths, one fused segment program at the emulator's default
 tile and block, the compute and memory legs' programs with the ring a TPU
-gets, the collective atom's program on four chips, and Qwen2-1.5B's
-decode step, whose profile must count the FLOPs its shapes imply (the TPU
-emits matmuls as convolutions).  Qwen2-7B's
-tensor-parallel prefill, compiled for the four chips of the described
-v5e:2x2, must profile to the wire its sharded step moves, and the
-one-chip Qwen2-1.5B steps to the totals they have always had.
+gets, the collective leg's two loops on four chips (in the atom's program
+and in a fused segment), and Qwen2-1.5B's decode step, whose profile must
+count the FLOPs its shapes imply (the TPU emits matmuls as convolutions).
+Qwen2-7B's tensor-parallel prefill, compiled for the four chips of the
+described v5e:2x2, must profile to the wire its sharded step moves, and
+the one-chip Qwen2-1.5B steps to the totals they have always had.
 
 The topology is described inside a module-scoped fixture and never while
 a module is imported: only one process may load the TPU library, and the
@@ -338,23 +338,92 @@ def test_qwen2_7b_tp_prefill_profiles_the_wire_it_moves(v5e_2x2):
     assert sum(got.values()) <= 1.05 * sum(want.values()), got
 
 
-def test_collective_plan_compiles_for_four_chips(v5e_2x2):
-    """The collective atom's one program, a loop of its all-reduce over the
-    fixed block with a traced trip count, compiles for the four chips of a
-    v5e:2x2 with the all-reduce in the loop's body: one compilation serves
-    every wire amount, as on the fused path."""
+def _four_chip_mesh(v5e_2x2):
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, 4), ("data", "model"), devices=v5e_2x2.devices)
+
+
+def _collective_plan(mesh):
+    """The collective atom's program, on the group carry."""
     from jax.sharding import NamedSharding
 
-    from repro.core.atoms import COLL_BLOCK_ELEMS, CollectiveAtom
-    from repro.launch.mesh import make_mesh
-
-    mesh = make_mesh((1, 4), ("data", "model"), devices=v5e_2x2.devices)
+    from repro.core.atoms import CollectiveAtom
     atom = CollectiveAtom(mesh)
-    text = atom._burn_fn().lower(
-        _sds(NamedSharding(mesh, P("model")), (4 * COLL_BLOCK_ELEMS,)),
-        _sds(NamedSharding(mesh, P()), (), jnp.int32)).compile().as_text()
-    comps = _computations(text)
-    bodies = [b for t in comps.values()
-              for b in re.findall(r" while\(.*?body=%([\w.\-]+)", t)]
-    assert len(bodies) == 1, bodies
-    assert "all-reduce" in _inlined(comps, bodies[0])
+    return atom._burn_fn().lower(
+        _sds(NamedSharding(mesh, P("model")), atom.loop_operand().shape),
+        _sds(NamedSharding(mesh, P()), (), jnp.int32)).compile()
+
+
+def _fused_segment_with_wire(mesh):
+    """The fused segment program the four-chip cell replays: compute,
+    memory and collective legs, with the ring a TPU gets."""
+    from jax.sharding import NamedSharding
+
+    from repro.core.atoms import CollectiveAtom, ring_windows
+    from repro.core.schedule import SegmentRunner
+    atom = CollectiveAtom(mesh)
+    runner = SegmentRunner(collective=atom)
+    whole = NamedSharding(mesh, P())
+    ring = (ring_windows(runner.block_bytes, "tpu"),
+            runner.block_bytes // 512, 128)
+    return runner._fn(8, True, True, True).lower(
+        _sds(whole, ring),
+        (_sds(whole, (COMPUTE_GROUP, runner.tile, runner.tile)),
+         _sds(whole, (), jnp.int32),
+         _sds(NamedSharding(mesh, P("model")), atom.loop_operand().shape)),
+        _sds(whole, (8, 3), jnp.int32)).compile()
+
+
+#: an all-reduce in a compiled module's text, with its f32 result's dims
+_ALL_REDUCE = re.compile(r"= f32\[([\d,]+)\]\{[^}]*\} all-reduce(-start)?\(")
+
+
+def _assert_collective_leg(compiled):
+    """The collective leg is two loops, each with one all-reduce in its
+    body and no further loop, no ``kOutput`` fusion and no matmul, so the
+    benchmark's trace reduction counts each whole to the collective leg:
+    one all-reduces a group of ``COLL_GROUP`` blocks a shard, the other
+    one block.  Returns the computations and the loops' bodies by the
+    elements their all-reduce moves."""
+    from repro.core.atoms import COLL_BLOCK_ELEMS, COLL_GROUP
+    comps = _computations(compiled.as_text())
+    loops = {}                                 # body -> computation holding it
+    for name, text in comps.items():
+        for body in re.findall(r" while\(.*?body=%([\w.\-]+)", text):
+            loops[body] = name
+    wire = {}
+    for body in loops:
+        text = _inlined(comps, body)
+        reduces = _ALL_REDUCE.findall(text)
+        if not reduces:
+            continue
+        assert len(reduces) == 1, (body, reduces)
+        assert " while(" not in text, body
+        assert "kind=kOutput" not in text, body
+        assert not re.search(r" (convolution|dot)\(", text), body
+        wire[int(reduces[0][0])] = body
+    assert sorted(wire) == [COLL_BLOCK_ELEMS, COLL_GROUP * COLL_BLOCK_ELEMS], \
+        wire
+    assert loops[wire[COLL_BLOCK_ELEMS]] == \
+        loops[wire[COLL_GROUP * COLL_BLOCK_ELEMS]]
+    return comps, loops, wire
+
+
+def test_collective_plan_compiles_for_four_chips(v5e_2x2):
+    """The collective atom's one program, its two loops of the all-reduce
+    with traced trip counts, compiles for the four chips of a v5e:2x2:
+    one compilation serves every wire amount, as on the fused path, and
+    its loops are the collective leg the trace reduction reads."""
+    _, loops, wire = _assert_collective_leg(
+        _collective_plan(_four_chip_mesh(v5e_2x2)))
+    assert len(loops) == 2, loops
+    assert all(loops[b] not in loops for b in wire.values())
+
+
+def test_fused_segment_collective_leg_compiles_for_four_chips(v5e_2x2):
+    """The four-chip cell's fused segment program runs the same two
+    collective loops as the atom's plan, each directly in the scan's body
+    beside the compute and memory legs."""
+    _, loops, wire = _assert_collective_leg(
+        _fused_segment_with_wire(_four_chip_mesh(v5e_2x2)))
+    assert all(loops[b] in loops for b in wire.values()), loops
